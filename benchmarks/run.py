@@ -68,6 +68,8 @@ def main() -> None:
     p.add_argument("--bench-dir", default=str(REPO_ROOT),
                    help="where BENCH_<suite>.json trajectories live")
     args = p.parse_args()
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache()
     if args.fast:
         os.environ["REPRO_BENCH_FAST"] = "1"
 

@@ -89,8 +89,10 @@ def _fused_vs_unfused(ctxs, fast: bool):
     for i, ctx in enumerate(ctxs):
         engines, us = {}, {}
         for fused in (True, False):
+            # 128-token pages: the TPU paged kernel needs lane-aligned
+            # pages, smaller ones take the op's oracle even when fused
             eng = make_engine(ARCH, mode="native", fuse_kernels=fused,
-                              max_lanes=4, page_size=8, max_ctx=ctx)
+                              max_lanes=4, page_size=128, max_ctx=ctx)
             active = fused_decode_active(eng)
             if i == 0:      # route report once per polarity (CI greps it)
                 emit("serve/decode_path", 0.0,
@@ -248,7 +250,7 @@ def main():
     # fused-vs-unfused decode column + the dispatch-route report (the fused
     # engine must stream pages through the fused kernel and the unfused one
     # must not — a silent fallback fails the bench, and CI greps the rows)
-    _fused_vs_unfused((32,) if fast else (32, 64), fast)
+    _fused_vs_unfused((128,) if fast else (128, 256), fast)
 
 
 if __name__ == "__main__":

@@ -25,9 +25,12 @@ CSV rows (name,us_per_call,derived — `derived` is ';'-separated):
                             (lossless resume format) and the int8 serving
                             export ratio (qsave.export_int8, ≥3x)
 
-The DP rows run in a subprocess (virtual host devices must be configured
-before jax initializes) over a fixed n_shards=4, so every layout computes
-bit-identical math — the column isolates parallel speedup + wire cost.
+The DP rows run over a fixed n_shards=4, so every layout computes
+bit-identical math — the column isolates parallel speedup + wire cost.  On
+a TPU backend they run in-process on the chip's own devices (and are
+printed as not measured with fewer than four); on CPU they run in a
+subprocess, because virtual host devices must be configured before jax
+initializes.
 
 Scale knobs: REPRO_BENCH_FAST drops the largest config and shortens the
 timed window.  On this CPU container both paths dispatch to the XLA
@@ -191,8 +194,19 @@ def _ckpt_bench(fast: bool):
 
 
 def _dp_scaling(fast: bool):
-    """Spawn the DP worker (device count must precede jax init) and re-emit
-    its rows into this process's record stream."""
+    """DP rows: in-process on TPU devices; on CPU, spawn the DP worker
+    (virtual device count must precede jax init) and re-emit its rows into
+    this process's record stream."""
+    import jax
+
+    if jax.default_backend() != "cpu":
+        n = len(jax.devices())
+        if n < 4:
+            print(f"train/dp_scaling: not measured (needs 4 "
+                  f"{jax.default_backend()} devices, have {n})")
+            return
+        _dp_rows(emit)
+        return
     env = dict(os.environ,
                XLA_FLAGS="--xla_force_host_platform_device_count=4",
                JAX_PLATFORMS="cpu")
@@ -210,7 +224,8 @@ def _dp_scaling(fast: bool):
             emit(name, float(us), derived)
 
 
-def _dp_worker():
+def _dp_rows(row):
+    """Time the DP layouts; `row(name, us, derived)` records each row."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -251,13 +266,13 @@ def _dp_worker():
         for sync, tag in (("int_ring", "intwire"), ("psum", "f32wire")):
             dt, cv, n, cost = run(dp, sync)
             base_us[(dp, tag)] = dt * 1e6
-            print(f"ROW,train/dp{dp}_{tag},{dt * 1e6:.1f},"
-                  f"tok_s={tokens / dt:.1f};steps={n};cv={cv:.3f};"
-                  f"arch={name};" + roofline_derived(cost, dt))
+            row(f"train/dp{dp}_{tag}", dt * 1e6,
+                f"tok_s={tokens / dt:.1f};steps={n};cv={cv:.3f};"
+                f"arch={name};" + roofline_derived(cost, dt))
     ratio = base_us[(1, 'intwire')] / base_us[(4, 'intwire')]
     wire = base_us[(4, 'f32wire')] / base_us[(4, 'intwire')]
-    print(f"ROW,train/dp_scaling,0.0,"
-          f"dp4_vs_dp1={ratio:.2f}x;f32_vs_int_at_dp4={wire:.2f}x")
+    row("train/dp_scaling", 0.0,
+        f"dp4_vs_dp1={ratio:.2f}x;f32_vs_int_at_dp4={wire:.2f}x")
 
     # wire-codec A/B at dp=2, wire-bits=8: packed tree codec (one ring,
     # two-per-int16 hops) vs the per-leaf unpacked rings — bit-identical
@@ -284,17 +299,18 @@ def _dp_worker():
 
     pe, pn = hop_elems("packed")
     ue, un = hop_elems("leaf")
-    print(f"ROW,train/wire_codec,{dt_p * 1e6:.1f},"
-          f"packed_us={dt_p * 1e6:.1f};unpacked_us={dt_u * 1e6:.1f};"
-          f"packed_vs_unpacked={dt_u / dt_p:.2f}x;"
-          f"hop_elems_packed={pe};hop_elems_unpacked={ue};"
-          f"elem_reduction={ue / pe:.2f}x;"
-          f"ppermutes_packed={pn};ppermutes_unpacked={un};"
-          f"dp=2;wire_bits=8")
+    row("train/wire_codec", dt_p * 1e6,
+        f"packed_us={dt_p * 1e6:.1f};unpacked_us={dt_u * 1e6:.1f};"
+        f"packed_vs_unpacked={dt_u / dt_p:.2f}x;"
+        f"hop_elems_packed={pe};hop_elems_unpacked={ue};"
+        f"elem_reduction={ue / pe:.2f}x;"
+        f"ppermutes_packed={pn};ppermutes_unpacked={un};"
+        f"dp=2;wire_bits=8")
 
 
 if __name__ == "__main__":
     if "--dp-worker" in sys.argv:
-        _dp_worker()
+        _dp_rows(lambda name, us, derived:
+                 print(f"ROW,{name},{us:.1f},{derived}"))
     else:
         main()

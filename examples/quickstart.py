@@ -37,6 +37,8 @@ def train(qcfg, steps=60):
 if __name__ == "__main__":
     from repro.core import registered_quantizers
     from repro.kernels.ops import dispatch_banner
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache()
     print(dispatch_banner())
     print("registered quantizers:", ", ".join(registered_quantizers()))
     print("training the same tiny LM under four numeric configs...")

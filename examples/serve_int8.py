@@ -32,6 +32,7 @@ import jax.numpy as jnp
 
 from repro.configs import get
 from repro.core import preset
+from repro.launch.cache import use_compile_cache
 from repro.models import build_model
 from repro.serving import Engine, greedy_token, poisson_traffic, run_load
 
@@ -116,6 +117,7 @@ def main():
     p.add_argument("--legacy", action="store_true",
                    help="raw serve_step loop instead of the engine")
     args = p.parse_args()
+    use_compile_cache()
     if not args.max_ctx:
         args.max_ctx = args.prompt_len + 8 + args.gen
 

@@ -37,6 +37,7 @@ from repro.core import preset
 from repro.core.qconfig import PRESETS
 from repro.data import TokenTask
 from repro.data.synthetic import Prefetcher
+from repro.launch.cache import use_compile_cache
 from repro.launch.train import make_train_step
 from repro.models import build_model
 from repro.optim import dr_bits_schedule, init_momentum, parse_boundaries
@@ -78,6 +79,7 @@ def main():
                         "--ckpt-dir (any dp dividing --n-shards)")
     p.add_argument("--save-every", type=int, default=50)
     args = p.parse_args()
+    use_compile_cache()
 
     arch = ArchConfig(name="int8-lm", family="lm", n_layers=args.layers,
                       d_model=args.d_model, n_heads=args.d_model // 64 or 2,

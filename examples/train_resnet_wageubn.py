@@ -19,6 +19,7 @@ from benchmarks.common import image_task, train_resnet  # noqa: E402
 from repro.core import preset  # noqa: E402
 from repro.data import resolve_image_task  # noqa: E402
 from repro.kernels.ops import dispatch_banner, dispatch_report  # noqa: E402
+from repro.launch.cache import use_compile_cache  # noqa: E402
 from repro.optim import parse_boundaries  # noqa: E402
 
 
@@ -33,6 +34,7 @@ def main():
                         "shrinks by one bit (e.g. '60,90'); empty = flat "
                         "at k_gw")
     args = p.parse_args()
+    use_compile_cache()
     bounds = parse_boundaries(args.dr_boundaries)
     if args.data_dir:
         task, data = resolve_image_task(64, data_dir=args.data_dir)

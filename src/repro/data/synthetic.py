@@ -15,6 +15,7 @@ Tasks:
 """
 from __future__ import annotations
 
+import functools
 import queue
 import threading
 from dataclasses import dataclass
@@ -73,14 +74,18 @@ class ImageTask:
         rs = np.random.RandomState(
             (self.seed * 1_000_003 + step * 7919 + start) % (2 ** 31))
         labels = rs.randint(0, self.num_classes, size=count).astype(np.int32)
-        # class-conditional means on a fixed random direction per class
-        proto_rs = np.random.RandomState(self.seed + 12345)
-        protos = proto_rs.randn(self.num_classes, self.img_size,
-                                self.img_size, 3).astype(np.float32)
-        imgs = (protos[labels]
+        imgs = (self._protos[labels]
                 + 0.8 * rs.randn(count, self.img_size, self.img_size, 3)
                 ).astype(np.float32)
         return {"images": imgs, "labels": labels}
+
+    @functools.cached_property
+    def _protos(self) -> np.ndarray:
+        """Class-conditional means on a fixed random direction per class,
+        drawn once per task (600 MB at 1000 classes x 224 px)."""
+        proto_rs = np.random.RandomState(self.seed + 12345)
+        return proto_rs.randn(self.num_classes, self.img_size,
+                              self.img_size, 3).astype(np.float32)
 
     def holdout_batch(self, i: int) -> dict:
         """Held-out eval batches: fresh steps the model never trains on —

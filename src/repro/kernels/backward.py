@@ -35,9 +35,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
-
-from ._compat import CompilerParams as _CompilerParams
-from ._compat import pltpu
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _payload_dtype(k: int):
@@ -90,22 +88,12 @@ def _bwd_call(g, other, scal, out_shape, specs, out_spec, grid, *,
               mode, k, dgrad, interpret):
     two = mode == "flag"
     bo = out_spec.block_shape
-    if pltpu is not None:
-        scratch = [pltpu.VMEM(bo, jnp.int32),
-                   pltpu.VMEM(bo, jnp.int32) if two else None]
-    else:  # pragma: no cover
-        scratch = [pl.MemorySpace.ANY, pl.MemorySpace.ANY if two else None]
-    if not two:
-        scratch = scratch[:1]
+    scratch = [pltpu.VMEM(bo, jnp.int32) for _ in range(2 if two else 1)]
 
     def kernel(g_ref, b_ref, s_ref, o_ref, acc1, acc2=None):
         _bwd_kernel(g_ref, b_ref, s_ref, o_ref, acc1, acc2,
                     mode=mode, k=k, dgrad=dgrad)
 
-    kwargs = {}
-    if not interpret and _CompilerParams is not None:
-        kwargs["compiler_params"] = _CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
     return pl.pallas_call(
         kernel,
         grid=grid,
@@ -113,8 +101,9 @@ def _bwd_call(g, other, scal, out_shape, specs, out_spec, grid, *,
         out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct(out_shape, jnp.float32),
         scratch_shapes=scratch,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-        **kwargs,
     )(g, other, scal.reshape(1, 3))
 
 

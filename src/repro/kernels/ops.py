@@ -28,21 +28,38 @@ paths):
 """
 from __future__ import annotations
 
+import collections
+import threading
+
 import jax
 import jax.numpy as jnp
 
 from . import autotune, ref
 from .backward import bwd_dgrad, bwd_wgrad
 from .page_gather import page_gather
-from .paged_attention import flash_attention, paged_attention
+from .paged_attention import (FLASH_VMEM_LIMIT, flash_attention,
+                              paged_attention)
 from .qmatmul import qmatmul
 from .quantize import cq_stochastic, quantize_fused
 from .selective_scan import selective_scan
-from .ubn import ubn_norm
+from .ubn import VMEM_BYTES_PER_ELEM, VMEM_LIMIT, ubn_norm
+
+# per op, the traced calls that took the XLA oracle on a TPU backend (shapes
+# past a kernel's VMEM budget or the TPU tiling rule, or manual-TP attention
+# whose amax must pmax); dispatch_report / dispatch_banner surface it, so no
+# oracle route is silent
+ORACLE_ON_TPU: collections.Counter = collections.Counter()
+_ORACLE_LOCK = threading.Lock()      # programs may trace on several threads
 
 
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
+
+
+def _oracle(op: str) -> None:
+    if _on_tpu():
+        with _ORACLE_LOCK:
+            ORACLE_ON_TPU[op] += 1
 
 
 def qmatmul_op(a8, b8, requant_inv=None, *, lim=127.0, force_kernel=False):
@@ -152,17 +169,34 @@ def wgrad_op(a8, g, scal, *, mode="affine", k=8, force_kernel=False):
 
 
 # the UBN kernel holds the full statistics axis in one VMEM block (the
-# stats need every element); in + out f32 blocks => 8 bytes per element of
-# (stats_axis x tile).  Tiles shrink to fit this budget, and shapes whose
-# statistics axis alone exceeds it fall back to the XLA oracle.
-_UBN_VMEM_BUDGET = 4 * 2 ** 20
+# stats need every element), so a block is (stats_axis x tile) and costs
+# VMEM_BYTES_PER_ELEM per element.  Tiles shrink to fit the kernel's
+# VMEM_LIMIT, and shapes where no legal tile fits fall back to the XLA
+# oracle (counted in ORACLE_ON_TPU).
+
+
+def _ubn_axes(kind: str, m: int, n: int) -> tuple[int, int, int]:
+    """(statistics axis, tiled axis, TPU alignment of the tiled axis): a
+    "batch" tile is the block's last dim (lanes, 128), a row tile its
+    second-to-last (sublanes, 8)."""
+    return (m, n, 128) if kind == "batch" else (n, m, 8)
+
+
+def _ubn_legal(kind: str, m: int, n: int, bt: int) -> bool:
+    """A TPU block dim is a multiple of its alignment or the whole axis
+    (ubn_norm clamps bt to the axis, so anything past it is the axis)."""
+    _, other, align = _ubn_axes(kind, m, n)
+    return bt >= other or bt % align == 0
 
 
 def _ubn_tile(kind: str, m: int, n: int) -> int | None:
-    """Largest safe tile along the non-statistics axis, or None -> oracle."""
-    stats_axis = m if kind == "batch" else n
-    fit = _UBN_VMEM_BUDGET // (8 * max(stats_axis, 1))
-    return None if fit < 8 else min(256, fit)
+    """Largest legal tile (<= 256) along the non-statistics axis whose
+    block fits the VMEM budget, or None -> oracle."""
+    stats, other, align = _ubn_axes(kind, m, n)
+    fit = min(256, VMEM_LIMIT // (VMEM_BYTES_PER_ELEM * max(stats, 1)))
+    if other <= fit:
+        return other
+    return (fit - fit % align) or None
 
 
 def ubn_norm_op(x, gamma, beta=None, *, kind="rms", k_mu=16, k_sigma=16,
@@ -179,9 +213,9 @@ def ubn_norm_op(x, gamma, beta=None, *, kind="rms", k_mu=16, k_sigma=16,
 
     Returns:
       (M, N) f32 on the k_BN/k_gamma grid, bit-identical to the unfused
-      sim-mode composition in core/qnorm.py.  Shapes whose statistics axis
-      cannot fit a VMEM block (huge flattened batch for "batch") lower
-      through the XLA oracle instead — same math.
+      sim-mode composition in core/qnorm.py.  Shapes where no legal tile
+      fits a VMEM block (huge flattened batch for "batch") lower through
+      the XLA oracle instead — same math, counted in ORACLE_ON_TPU.
     """
     kw = dict(kind=kind, k_mu=k_mu, k_sigma=k_sigma, k_bn=k_bn,
               k_gamma=k_gamma, k_beta=k_beta, eps=eps)
@@ -192,9 +226,11 @@ def ubn_norm_op(x, gamma, beta=None, *, kind="rms", k_mu=16, k_sigma=16,
         # bt is bit-identical — tests/test_autotune.py proves it)
         tiles = autotune.tiles_for(
             "ubn_norm", (x.shape, kind), {"bt": bt})
-        tiles["bt"] = min(tiles["bt"], bt)
+        tuned = min(tiles["bt"], bt)
+        tiles["bt"] = tuned if _ubn_legal(kind, *x.shape, tuned) else bt
         return ubn_norm(x, gamma, beta, interpret=not _on_tpu(),
                         **tiles, **kw)
+    _oracle("ubn_norm")
     return ref.ubn_norm_ref(x, gamma, beta, **kw)
 
 
@@ -221,9 +257,10 @@ def page_gather_op(pages, table, *, force_kernel=False):
 
 
 # the decode score pass holds one lane's full (H, T) f32 score row in VMEM
-# scratch; the flash kernel holds full-batch (B, qc, H[, dh]) m/l/o blocks.
-# Shapes past these budgets lower through the XLA oracles instead (same
-# math), mirroring the UBN tile guard above.
+# scratch; the flash kernel holds full-batch (B, H, qc, .) m/l/acc scratch.
+# Shapes past these budgets, or whose blocks break the TPU tiling rule,
+# lower through the XLA oracles instead (same math), counted in
+# ORACLE_ON_TPU like the UBN tile guard above.
 _ATTN_VMEM_BUDGET = 4 * 2 ** 20
 
 
@@ -232,9 +269,11 @@ def paged_attention_fits(kvg: int, t: int) -> bool:
     return 4 * kvg * t <= _ATTN_VMEM_BUDGET
 
 
-def flash_attention_fits(b: int, qc: int, h: int, dh: int) -> bool:
-    """Whether the flash kernel's full-batch m/l/o scratch fits VMEM."""
-    return 4 * b * qc * h * (dh + 2) <= _ATTN_VMEM_BUDGET
+def flash_attention_fits(b: int, qc: int, h: int, dh: int, kc: int) -> bool:
+    """Whether the flash kernel's blocks and scratch fit its VMEM limit.
+    v5e compiles measured at most (32 dh + 8 kc) bytes per (batch, head,
+    query) row at dh 128."""
+    return b * qc * h * (32 * dh + 8 * kc) <= FLASH_VMEM_LIMIT
 
 
 def paged_attention_op(q8, k_pages, v_pages, table, q_pos, t_valid,
@@ -262,11 +301,14 @@ def paged_attention_op(q8, k_pages, v_pages, table, q_pos, t_valid,
     """
     page = k_pages.shape[1]
     fits = paged_attention_fits(q8.shape[1], table.shape[1] * page)
+    # the score row takes one page per grid step at lane offset j * page,
+    # which the TPU compiler accepts only for 128-aligned pages
+    aligned = page % 128 == 0
     # under manual TP (amax_sync active) the probability amax must pmax
     # over the model axis — a mesh collective the Pallas kernel body cannot
     # issue, so sharded decode stays on the (bit-identical) oracle
     tp_sync = ref._AMAX_SYNC_AXIS is not None
-    if not tp_sync and (_on_tpu() or force_kernel) and fits:
+    if not tp_sync and fits and (force_kernel or (_on_tpu() and aligned)):
         # the tunable here is the pipeliner's dimension_semantics hint —
         # the kv chunking itself is amax granularity (numerics), not a knob
         tiles = autotune.tiles_for(
@@ -276,6 +318,7 @@ def paged_attention_op(q8, k_pages, v_pages, table, q_pos, t_valid,
                                q_scale, k_scale, v_scale, sm_scale=sm_scale,
                                k_a=k_a, ds=tiles["ds"],
                                interpret=not _on_tpu())
+    _oracle("paged_attention")
     return ref.paged_attention_ref(q8, k_pages, v_pages, table, q_pos,
                                    t_valid, q_scale, k_scale, v_scale,
                                    sm_scale=sm_scale, k_a=k_a)
@@ -304,11 +347,16 @@ def flash_attention_op(q8, k8, v8, q_pos, k_pos, k_valid, q_scale, k_scale,
       (B, S, H, dh) f32 pre-Q_A output (padded rows included).
     """
     b, s, h, dh = q8.shape
-    fits = flash_attention_fits(b, min(q_chunk, s), h, dh)
+    t = k8.shape[1]
+    fits = flash_attention_fits(b, q_chunk, h, dh, kv_chunk)
+    # TPU tiling rule on the (q_chunk, 1) position column and the
+    # (1, kv_chunk) key rows
+    aligned = ((q_chunk % 8 == 0 or q_chunk == s)
+               and (kv_chunk % 128 == 0 or kv_chunk == t))
     # same manual-TP routing rule as paged_attention_op: in-kernel amax
     # cannot pmax, so sharded prefill/training takes the oracle
     tp_sync = ref._AMAX_SYNC_AXIS is not None
-    if not tp_sync and (_on_tpu() or force_kernel) and fits:
+    if not tp_sync and fits and (force_kernel or (_on_tpu() and aligned)):
         # q_chunk/kv_chunk are per-chunk amax granularity — numerics, never
         # autotuned; only the scheduling hint is a legal knob here
         tiles = autotune.tiles_for(
@@ -320,6 +368,7 @@ def flash_attention_op(q8, k8, v8, q_pos, k_pos, k_valid, q_scale, k_scale,
                                sm_scale=sm_scale, q_chunk=q_chunk,
                                kv_chunk=kv_chunk, k_a=k_a, ds=tiles["ds"],
                                interpret=not _on_tpu())
+    _oracle("flash_attention")
     return ref.flash_attention_ref(q8, k8, v8, q_pos, k_pos, k_valid,
                                    q_scale, k_scale, v_scale, causal=causal,
                                    sm_scale=sm_scale, q_chunk=q_chunk,
@@ -355,12 +404,15 @@ def dispatch_report(cfg=None) -> dict:
     """What the ops above resolve to right now.
 
     Returns {"backend", "route" ("kernel" on TPU else "oracle"),
-    "ops": {name: route}}; with a QConfig also "mode" and "fused" (whether
-    native mode routes backward/UBN/attention through the fused ops).
+    "ops": {name: route}, "oracle_on_tpu": {name: traced calls that took
+    the oracle on TPU so far}}; with a QConfig also "mode" and "fused"
+    (whether native mode routes backward/UBN/attention through the fused
+    ops).
     """
     route = "kernel" if _on_tpu() else "oracle"
     rep = {"backend": jax.default_backend(), "route": route,
-           "ops": {name: route for name in OPS}}
+           "ops": {name: route for name in OPS},
+           "oracle_on_tpu": dict(ORACLE_ON_TPU)}
     rep["autotune"] = {"entries": len(autotune.entries()),
                        "dir": autotune.cache_dir()}
     from repro.runtime.compress import default_wire_codec
@@ -374,10 +426,13 @@ def dispatch_report(cfg=None) -> dict:
 
 def dispatch_banner(cfg=None) -> str:
     """One-line startup banner, e.g.
-    '[kernels] backend=cpu route=oracle mode=native bwd/ubn=fused
-    attn=fused'."""
+    '[kernels] backend=tpu route=kernel oracle_on_tpu=ubn_norm:9
+    mode=native bwd/ubn=fused attn=fused ...'."""
     rep = dispatch_report(cfg)
-    line = f"[kernels] backend={rep['backend']} route={rep['route']}"
+    fell = ",".join(f"{k}:{v}"
+                    for k, v in sorted(rep["oracle_on_tpu"].items()))
+    line = (f"[kernels] backend={rep['backend']} route={rep['route']} "
+            f"oracle_on_tpu={fell or 0}")
     if cfg is not None:
         fused = "fused" if rep["fused"] else "unfused"
         line += f" mode={rep['mode']} bwd/ubn={fused} attn={fused}"

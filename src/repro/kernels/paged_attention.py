@@ -41,13 +41,17 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams as _CompilerParams
-from ._compat import pltpu
 # the same decomposition formulas run in-register here and in the XLA
 # oracles — one definition keeps the kernel-vs-oracle bit-exactness
 # contract in one place
-from .ref import NEG_INF, _grid_decompose, _pow2_ceil
+from .ref import NEG_INF, _grid_decompose, _grid_payload, _grid_step
+
+# the flash kernel keeps full-batch (b, h, q_chunk, .) m / l / acc scratch
+# and double-buffered q and output blocks in VMEM; ops.flash_attention_fits
+# keeps the shapes that take the kernel within this limit
+FLASH_VMEM_LIMIT = 64 * 2 ** 20
 
 
 # --------------------------------------------------------------------------
@@ -81,9 +85,9 @@ def _decode_ml_kernel(table_ref, qpos_ref, tval_ref, q_ref, k_ref, kq_ref,
     def _reduce():
         # one max + one full-axis sum over the VMEM score row — the same
         # single reductions the unfused softmax runs
-        m = jnp.max(sc_ref[...], axis=-1)
+        m = jnp.max(sc_ref[...], axis=-1, keepdims=True)
         m_ref[0] = m
-        l_ref[0] = jnp.sum(jnp.exp(sc_ref[...] - m[:, None]), axis=-1)
+        l_ref[0] = jnp.sum(jnp.exp(sc_ref[...] - m), axis=-1, keepdims=True)
 
 
 def _decode_out_kernel(table_ref, qpos_ref, tval_ref, q_ref, k_ref, v_ref,
@@ -97,7 +101,7 @@ def _decode_out_kernel(table_ref, qpos_ref, tval_ref, q_ref, k_ref, v_ref,
 
     sc = _page_scores(q_ref[0], k_ref[0], kq_ref[0, 0], sm_scale,
                       qpos_ref[i], tval_ref[0], j, page, kv, g)
-    p = jnp.exp(sc - m_ref[0][:, None]) / l_ref[0][:, None]
+    p = jnp.exp(sc - m_ref[0]) / l_ref[0]
     s_ = 2.0 ** (k_a - 1)
     pg = jnp.round(p * s_) / s_                     # qprobs (Q_A grid)
     lim = s_ - 1.0
@@ -141,15 +145,14 @@ def paged_attention(q8: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     tval = jnp.asarray(t_valid, jnp.int32).reshape(1)
     kq = jnp.asarray(q_scale * k_scale, jnp.float32).reshape(1, 1)
 
-    kwargs = {}
-    if not interpret and _CompilerParams is not None:
-        kwargs["compiler_params"] = _CompilerParams(
-            dimension_semantics=tuple(ds))
+    params = pltpu.CompilerParams(dimension_semantics=tuple(ds))
     qspec = pl.BlockSpec((1, kvg, dh), lambda i, j, *_: (i, 0, 0))
     pagespec = pl.BlockSpec((1, page, kv, dh),
                             lambda i, j, tref, *_: (tref[i, j], 0, 0, 0))
     sspec = pl.BlockSpec((1, 1), lambda i, j, *_: (0, 0))
-    rowspec = pl.BlockSpec((1, kvg), lambda i, j, *_: (i, 0))
+    # per-row m / l as (B, H, 1) columns: a (1, H) block of a (B, H) array
+    # breaks the TPU tiling rule, a (1, H, 1) block spans its last two dims
+    rowspec = pl.BlockSpec((1, kvg, 1), lambda i, j, *_: (i, 0, 0))
 
     m, l = pl.pallas_call(
         functools.partial(_decode_ml_kernel, page=page, kv=kv, g=g, nb=nb,
@@ -161,9 +164,9 @@ def paged_attention(q8: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
             out_specs=[rowspec, rowspec],
             scratch_shapes=[pltpu.VMEM((kvg, nb * page), jnp.float32)],
         ),
-        out_shape=[jax.ShapeDtypeStruct((b, kvg), jnp.float32)] * 2,
+        out_shape=[jax.ShapeDtypeStruct((b, kvg, 1), jnp.float32)] * 2,
+        compiler_params=params,
         interpret=interpret,
-        **kwargs,
     )(table, qpos, tval, q8, k_pages, kq)
 
     # the single probability amax: max(p) per row is exp(0)/l == 1.0/l, so
@@ -171,7 +174,7 @@ def paged_attention(q8: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     # reduces over `l` alone — a scalar reduction between the passes
     s_ = 2.0 ** (k_a - 1)
     amax_pg = jnp.round(jnp.max(1.0 / l) * s_) / s_
-    step = jnp.maximum(_pow2_ceil(amax_pg), 2.0 ** -24) * 2.0 ** (1 - k_a)
+    step = _grid_step(amax_pg, k_a)
     pinv = (jnp.float32(1.0) / step).reshape(1, 1)
     pv = (step * v_scale).reshape(1, 1).astype(jnp.float32)
 
@@ -187,8 +190,8 @@ def paged_attention(q8: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
             scratch_shapes=[pltpu.VMEM((kvg, dh), jnp.int32)],
         ),
         out_shape=jax.ShapeDtypeStruct((b, kvg, dh), jnp.float32),
+        compiler_params=params,
         interpret=interpret,
-        **kwargs,
     )(table, qpos, tval, q8, k_pages, v_pages, kq, m, l, pinv, pv)
 
 
@@ -198,11 +201,13 @@ def paged_attention(q8: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, qp_ref, kp_ref, kval_ref, qs_ref,
-                  ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref, *, b, kv, g,
-                  dh, nk, causal, sm_scale, k_a):
+                  ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref, *, b, h, g,
+                  causal, sm_scale, k_a):
+    """One (q-chunk, kv-chunk) cell over head-major blocks: q (b, h, qc,
+    dh), k/v (b, kv, kc, dh), m/l (b, h, qc, 1).  Every dot and softmax
+    step runs on a 2-D (rows, lanes) tile per (batch, head), the layout
+    Mosaic lowers; the GridQuantizer amaxes still span the full block."""
     ik = pl.program_id(1)
-    qc = q_ref.shape[1]
-    kc = k_ref.shape[1]
     s_ = 2.0 ** (k_a - 1)
 
     @pl.when(ik == 0)
@@ -213,60 +218,44 @@ def _flash_kernel(q_ref, k_ref, v_ref, qp_ref, kp_ref, kval_ref, qs_ref,
 
     # per-chunk GridQuantizer decompositions, amax over the FULL batch
     # block — bit-identical to the unfused per-chunk qeinsum entries
-    qf = q_ref[...].astype(jnp.float32) * qs_ref[0, 0]
-    q8, q_step = _grid_decompose(qf, k_a)
-    kf = k_ref[...].astype(jnp.float32) * ks_ref[0, 0]
-    k8, k_step = _grid_decompose(kf, k_a)
-    vf = v_ref[...].astype(jnp.float32) * vs_ref[0, 0]
-    v8, v_step = _grid_decompose(vf, k_a)
+    q8, q_step = _grid_decompose(q_ref[...].astype(jnp.float32)
+                                 * qs_ref[0, 0], k_a)
+    k8, k_step = _grid_decompose(k_ref[...].astype(jnp.float32)
+                                 * ks_ref[0, 0], k_a)
+    v8, v_step = _grid_decompose(v_ref[...].astype(jnp.float32)
+                                 * vs_ref[0, 0], k_a)
+    kval = kval_ref[...] != 0                                   # (1, kc)
+    mask = kval if not causal else (qp_ref[...] >= kp_ref[...]) & kval
+    heads = [(bi, hi) for bi in range(b) for hi in range(h)]
 
-    q8r = q8.reshape(b, qc, kv, g, dh)
-    sc = _tile_dots(q8r, k8, (q_step * k_step), swap=False)     # (b,qc,kv,g,kc)
-    sc = sc * sm_scale
-    kval = kval_ref[...] != 0
-    qp, kp = qp_ref[...], kp_ref[...]
-    mask = kval[None, :] if not causal else (
-        (qp[:, None] >= kp[None, :]) & kval[None, :])
-    sc = jnp.where(mask[None, :, None, None, :], sc, NEG_INF)
+    probs, m_new = {}, {}
+    for bi, hi in heads:
+        sc = jnp.dot(q8[bi, hi], k8[bi, hi // g].T,
+                     preferred_element_type=jnp.int32)          # (qc, kc)
+        sc = sc.astype(jnp.float32) * (q_step * k_step) * sm_scale
+        sc = jnp.where(mask, sc, NEG_INF)
+        m_new[bi, hi] = jnp.maximum(m_ref[bi, hi],
+                                    jnp.max(sc, axis=-1, keepdims=True))
+        p = jnp.exp(sc - m_new[bi, hi])
+        probs[bi, hi] = jnp.round(p * s_) / s_      # qprobs, unnormalized
+    # ONE amax over every head's probabilities, as the full-block
+    # decomposition takes it
+    p_amax = functools.reduce(jnp.maximum, [jnp.max(jnp.abs(p))
+                                            for p in probs.values()])
+    p_step = _grid_step(p_amax, k_a)
+    for bi, hi in heads:
+        p8 = _grid_payload(probs[bi, hi], p_step, k_a)
+        pv = jnp.dot(p8, v8[bi, hi // g], preferred_element_type=jnp.int32)
+        pv = pv.astype(jnp.float32) * (p_step * v_step)          # (qc, dh)
+        alpha = jnp.exp(m_ref[bi, hi] - m_new[bi, hi])
+        l_ref[bi, hi] = l_ref[bi, hi] * alpha + jnp.sum(
+            probs[bi, hi], axis=-1, keepdims=True)
+        acc_ref[bi, hi] = acc_ref[bi, hi] * alpha + pv
+        m_ref[bi, hi] = m_new[bi, hi]
 
-    m_old = m_ref[...].reshape(b, qc, kv, g)
-    m_new = jnp.maximum(m_old, jnp.max(sc, axis=-1))
-    p = jnp.exp(sc - m_new[..., None])
-    p = jnp.round(p * s_) / s_                      # qprobs, unnormalized
-    p8, p_step = _grid_decompose(p, k_a)
-    pv = _tile_dots(p8, v8, (p_step * v_step), swap=True)       # (b,qc,kv,g,dh)
-    alpha = jnp.exp(m_old - m_new)
-    l_new = l_ref[...].reshape(b, qc, kv, g) * alpha + jnp.sum(p, axis=-1)
-    o_new = acc_ref[...].reshape(b, qc, kv, g, dh) * alpha[..., None] + pv
-    m_ref[...] = m_new.reshape(b, qc, kv * g)
-    l_ref[...] = l_new.reshape(b, qc, kv * g)
-    acc_ref[...] = o_new.reshape(b, qc, kv * g, dh)
-
-    @pl.when(ik == nk - 1)
+    @pl.when(ik == pl.num_programs(1) - 1)
     def _flush():
-        o = acc_ref[...] / jnp.maximum(l_ref[...], 1e-9)[..., None]
-        o_ref[...] = o.reshape(b, qc, kv * g, dh)
-
-
-def _tile_dots(a8, b8, scale, *, swap):
-    """Per-(batch, kv-head) integer dots, rescaled to f32.
-
-    swap=False: scores — a8 (b, qc, kv, g, dh) x b8 (b, kc, kv, dh)
-    -> (b, qc, kv, g, kc).  swap=True: p·v — a8 (b, qc, kv, g, kc) x
-    b8 (b, kc, kv, dh) -> (b, qc, kv, g, dh).
-    """
-    b, qc, kv, g = a8.shape[:4]
-    outs = []
-    for bi in range(b):
-        per_h = []
-        for h in range(kv):
-            lhs = a8[bi, :, h].reshape(qc * g, a8.shape[-1])
-            rhs = b8[bi, :, h, :]
-            rhs = rhs if swap else rhs.T
-            acc = jnp.dot(lhs, rhs, preferred_element_type=jnp.int32)
-            per_h.append(acc.reshape(qc, g, acc.shape[-1]))
-        outs.append(jnp.stack(per_h, axis=1))       # (qc, kv, g, n)
-    return jnp.stack(outs, 0).astype(jnp.float32) * scale
+        o_ref[...] = acc_ref[...] / jnp.maximum(l_ref[...], 1e-9)
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "sm_scale", "q_chunk",
@@ -288,41 +277,37 @@ def flash_attention(q8: jax.Array, k8: jax.Array, v8: jax.Array,
     """
     b, s, h, dh = q8.shape
     t, kv = k8.shape[1], k8.shape[2]
-    g = h // kv
     nq, nk = s // q_chunk, t // kv_chunk
-    qpos = q_pos.astype(jnp.int32)
-    kpos = k_pos.astype(jnp.int32)
-    kval = k_valid.astype(jnp.int32)
+    # head-major operands (B, heads, len, dh): each kernel tile is then a
+    # (len, dh) matrix; positions ride as a (S, 1) column and (1, T) rows
+    qh, kh, vh = (x.transpose(0, 2, 1, 3) for x in (q8, k8, v8))
+    qpos = q_pos.astype(jnp.int32).reshape(s, 1)
+    kpos = k_pos.astype(jnp.int32).reshape(1, t)
+    kval = k_valid.astype(jnp.int32).reshape(1, t)
     scal = [jnp.asarray(v, jnp.float32).reshape(1, 1)
             for v in (q_scale, k_scale, v_scale)]
 
-    kwargs = {}
-    if not interpret and _CompilerParams is not None:
-        kwargs["compiler_params"] = _CompilerParams(
-            dimension_semantics=tuple(ds))
     sspec = pl.BlockSpec((1, 1), lambda iq, ik: (0, 0))
+    qspec = pl.BlockSpec((b, h, q_chunk, dh), lambda iq, ik: (0, 0, iq, 0))
+    kvspec = pl.BlockSpec((b, kv, kv_chunk, dh),
+                          lambda iq, ik: (0, 0, ik, 0))
+    krow = pl.BlockSpec((1, kv_chunk), lambda iq, ik: (0, ik))
     out = pl.pallas_call(
-        functools.partial(_flash_kernel, b=b, kv=kv, g=g, dh=dh, nk=nk,
+        functools.partial(_flash_kernel, b=b, h=h, g=h // kv,
                           causal=causal, sm_scale=sm_scale, k_a=k_a),
         grid=(nq, nk),
-        in_specs=[
-            pl.BlockSpec((b, q_chunk, h, dh), lambda iq, ik: (0, iq, 0, 0)),
-            pl.BlockSpec((b, kv_chunk, kv, dh), lambda iq, ik: (0, ik, 0, 0)),
-            pl.BlockSpec((b, kv_chunk, kv, dh), lambda iq, ik: (0, ik, 0, 0)),
-            pl.BlockSpec((q_chunk,), lambda iq, ik: (iq,)),
-            pl.BlockSpec((kv_chunk,), lambda iq, ik: (ik,)),
-            pl.BlockSpec((kv_chunk,), lambda iq, ik: (ik,)),
-            sspec, sspec, sspec,
-        ],
-        out_specs=pl.BlockSpec((b, q_chunk, h, dh),
-                               lambda iq, ik: (0, iq, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, s, h, dh), jnp.float32),
+        in_specs=[qspec, kvspec, kvspec,
+                  pl.BlockSpec((q_chunk, 1), lambda iq, ik: (iq, 0)),
+                  krow, krow, sspec, sspec, sspec],
+        out_specs=qspec,
+        out_shape=jax.ShapeDtypeStruct((b, h, s, dh), jnp.float32),
         scratch_shapes=[
-            pltpu.VMEM((b, q_chunk, h), jnp.float32),
-            pltpu.VMEM((b, q_chunk, h), jnp.float32),
-            pltpu.VMEM((b, q_chunk, h, dh), jnp.float32),
+            pltpu.VMEM((b, h, q_chunk, 1), jnp.float32),
+            pltpu.VMEM((b, h, q_chunk, 1), jnp.float32),
+            pltpu.VMEM((b, h, q_chunk, dh), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=tuple(ds), vmem_limit_bytes=FLASH_VMEM_LIMIT),
         interpret=interpret,
-        **kwargs,
-    )(q8, k8, v8, qpos, kpos, kval, *scal)
-    return out
+    )(qh, kh, vh, qpos, kpos, kval, *scal)
+    return out.transpose(0, 2, 1, 3)

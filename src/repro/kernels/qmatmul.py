@@ -21,11 +21,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 from jax.experimental import pallas as pl
-
-from ._compat import CompilerParams as _CompilerParams
-from ._compat import pltpu
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _qmm_kernel(a_ref, b_ref, o_ref, acc_ref):
@@ -88,12 +85,6 @@ def qmatmul(a8: jax.Array, b8: jax.Array, requant_inv: jax.Array | None = None,
     mm, nn, kk = m + pm, n + pn, k + pk
 
     grid = (mm // bm, nn // bn, kk // bk)
-    kwargs = {}
-    if not interpret and _CompilerParams is not None:
-        kwargs["compiler_params"] = _CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
-    scratch = (pltpu.VMEM((bm, bn), jnp.int32) if pltpu is not None
-               else pl.MemorySpace.ANY)  # pragma: no cover
     in_specs = [pl.BlockSpec((bm, bk), lambda i, j, l: (i, l)),
                 pl.BlockSpec((bk, bn), lambda i, j, l: (l, j))]
     if requant_inv is None:
@@ -110,8 +101,9 @@ def qmatmul(a8: jax.Array, b8: jax.Array, requant_inv: jax.Array | None = None,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, l: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mm, nn), out_dtype),
-        scratch_shapes=[scratch],
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-        **kwargs,
     )(*operands)
     return out[:m, :n]

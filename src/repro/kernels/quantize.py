@@ -17,6 +17,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 
 
@@ -53,8 +54,11 @@ def _cq_kernel(x_ref, bits_ref, s_ref, o_ref, *, dr):
     inv = s_ref[0, 0]
     v = x_ref[...] * inv
     f = jnp.floor(v)
-    u = (bits_ref[...] & jnp.uint32(0xFFFFFF)).astype(jnp.float32) \
-        * (2.0 ** -24)
+    # Mosaic has no uint32 -> f32 cast; the masked 24-bit value is
+    # non-negative, so the int32 view converts to the same float
+    u24 = lax.bitcast_convert_type(bits_ref[...] & jnp.uint32(0xFFFFFF),
+                                   jnp.int32)
+    u = u24.astype(jnp.float32) * (2.0 ** -24)
     y = f + (u < (v - f)).astype(jnp.float32)
     o_ref[...] = jnp.clip(y, -dr + 1.0, dr - 1.0).astype(jnp.int16)
 

@@ -167,12 +167,21 @@ def _grid_decompose(x: jax.Array, k: int):
     m = jnp.max(jnp.abs(x))
     if _AMAX_SYNC_AXIS is not None:
         m = jax.lax.pmax(m, _AMAX_SYNC_AXIS)
-    s = jnp.maximum(_pow2_ceil(m), 2.0 ** -24)
-    step = s * 2.0 ** (1 - k)
+    step = _grid_step(m, k)
+    return _grid_payload(x, step, k), step
+
+
+def _grid_step(amax, k: int):
+    """The GridQuantizer step for an amax: pow2_ceil(amax) (floored at
+    2^-24) times 2^(1-k)."""
+    return jnp.maximum(_pow2_ceil(amax), 2.0 ** -24) * 2.0 ** (1 - k)
+
+
+def _grid_payload(x: jax.Array, step, k: int) -> jax.Array:
+    """clip(round(x / step), +-(2^(k-1)-1)) as int8 (step is pow2)."""
     lim = 2.0 ** (k - 1) - 1.0
-    p8 = jnp.clip(jnp.round(x * (jnp.float32(1.0) / step)), -lim,
-                  lim).astype(jnp.int8)
-    return p8, step
+    return jnp.clip(jnp.round(x * (jnp.float32(1.0) / step)), -lim,
+                    lim).astype(jnp.int8)
 
 
 def paged_attention_ref(q8: jax.Array, k_pages: jax.Array, v_pages: jax.Array,

@@ -17,9 +17,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
-
-from ._compat import CompilerParams as _CompilerParams
-from ._compat import pltpu
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _ssm_kernel(a_ref, b_ref, c_ref, o_ref, h_ref, *, bs):
@@ -37,9 +35,15 @@ def _ssm_kernel(a_ref, b_ref, c_ref, o_ref, h_ref, *, bs):
 
 @functools.partial(jax.jit, static_argnames=("bd", "bs", "interpret"))
 def selective_scan(a: jax.Array, b: jax.Array, c: jax.Array, *,
-                   bd: int = 256, bs: int = 128,
+                   bd: int = 128, bs: int = 32,
                    interpret: bool = True) -> jax.Array:
-    """a, b: (B, S, D, N) f32; c: (B, S, N) f32 -> y: (B, S, D) f32."""
+    """a, b: (B, S, D, N) f32; c: (B, S, N) f32 -> y: (B, S, D) f32.
+
+    The (bs, bd, N) blocks pad N to 128 lanes on a TPU, so bd * bs stays at
+    4096: two double-buffered inputs then fit the 16 MiB scoped VMEM at
+    falcon-mamba-7b widths (v5e compile).  bd is the output block's lane
+    dim (a multiple of 128 or all of D), bs a multiple of 8 or all of S.
+    """
     bsz, s, d, n = a.shape
     bd, bs = min(bd, d), min(bs, s)
     pd, ps = (-d) % bd, (-s) % bs
@@ -51,12 +55,6 @@ def selective_scan(a: jax.Array, b: jax.Array, c: jax.Array, *,
     dd, ss = d + pd, s + ps
 
     grid = (bsz, dd // bd, ss // bs)
-    kwargs = {}
-    if not interpret and _CompilerParams is not None:
-        kwargs["compiler_params"] = _CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
-    scratch = (pltpu.VMEM((bd, n), jnp.float32) if pltpu is not None
-               else pl.MemorySpace.ANY)  # pragma: no cover
     out = pl.pallas_call(
         functools.partial(_ssm_kernel, bs=bs),
         grid=grid,
@@ -65,8 +63,9 @@ def selective_scan(a: jax.Array, b: jax.Array, c: jax.Array, *,
                   pl.BlockSpec((1, bs, n), lambda i, j, k: (i, k, 0))],
         out_specs=pl.BlockSpec((1, bs, bd), lambda i, j, k: (i, k, j)),
         out_shape=jax.ShapeDtypeStruct((bsz, ss, dd), jnp.float32),
-        scratch_shapes=[scratch],
+        scratch_shapes=[pltpu.VMEM((bd, n), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-        **kwargs,
     )(a, b, c)
     return out[:, :s, :d]

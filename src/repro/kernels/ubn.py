@@ -21,9 +21,9 @@ composition in core/qnorm.py — validated against ref.ubn_norm_ref.
 
 VMEM constraint: the statistics axis is held whole in each block (the
 stats need every element), so the per-block footprint is
-8 bytes x stats_axis x bt.  `ops.ubn_norm_op` shrinks `bt` to fit and
-falls back to the XLA oracle for shapes whose statistics axis alone
-exceeds the budget (e.g. a very large flattened batch under "batch").
+VMEM_BYTES_PER_ELEM x stats_axis x bt.  `ops.ubn_norm_op` picks a legal
+`bt` that fits VMEM_LIMIT and falls back to the XLA oracle for shapes
+where none does (e.g. a very large flattened batch under "batch").
 """
 from __future__ import annotations
 
@@ -32,6 +32,15 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+# v5e has 128 MiB of VMEM per core, and the 16 MiB scoped default cannot
+# hold a whole-statistics-axis block at ResNet shapes, so the kernel asks
+# for most of it.  v5e compiles of f32 blocks measured ~20 bytes per block
+# element (double-buffered input and output plus the kernel's temporaries);
+# the budget counts 24 to leave margin.
+VMEM_LIMIT = 96 * 2 ** 20
+VMEM_BYTES_PER_ELEM = 24
 
 
 def _qd(x, k: int):
@@ -115,6 +124,8 @@ def ubn_norm(x: jax.Array, gamma: jax.Array, beta: jax.Array | None, *,
         in_specs=[xs, vs, vs],
         out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct(oshape, jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",), vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
     )(x, gamma, beta)
     return out[:m, :n]

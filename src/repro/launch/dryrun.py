@@ -31,7 +31,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import ASSIGNED, get as get_arch
 from repro.configs.base import LM_SHAPES
 from repro.core.qconfig import preset
-from repro.launch.mesh import make_production_mesh, mesh_axes
+from repro.launch.mesh import auto_mesh, make_production_mesh, mesh_axes
 from repro.launch.roofline import parse_collectives
 from repro.launch.train import make_prefill, make_serve_step, make_train_step
 from repro.models import build_model
@@ -46,7 +46,7 @@ def make_mesh(multi_pod: bool):
     if _tiny():
         shape = (2, 2, 2) if multi_pod else (2, 2)
         axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-        return jax.make_mesh(shape, axes)
+        return auto_mesh(shape, axes)
     return make_production_mesh(multi_pod=multi_pod)
 
 
@@ -168,9 +168,6 @@ def _depth_points(acfg):
 
 def _cost_metrics(compiled):
     ca = compiled.cost_analysis() or {}
-    # older jax returns a one-element list of dicts, newer a flat dict
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
     colls = parse_collectives(compiled.as_text())
     return {
         "flops": float(ca.get("flops", 0.0)),

@@ -6,20 +6,28 @@ never touches jax device state.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def auto_mesh(shape, axes):
+    """jax.make_mesh with Auto axis types: the installed jax defaults to
+    Explicit, whose sharding-in-types rules reject the gathers and scatters
+    the auto-partitioned (non-shard_map) paths rely on."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_cpu_mesh(n_data: int = 1, n_model: int = 1, pod: int = 0):
-    """Small mesh over available devices (tests / smoke runs)."""
+    """Small mesh over the first available devices (tests, smoke runs,
+    and the chip's own devices)."""
     if pod:
-        return jax.make_mesh((pod, n_data, n_model),
-                             ("pod", "data", "model"))
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+        return auto_mesh((pod, n_data, n_model), ("pod", "data", "model"))
+    return auto_mesh((n_data, n_model), ("data", "model"))
 
 
 def make_replica_meshes(n_replicas: int, tp: int = 1):

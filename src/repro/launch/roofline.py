@@ -9,10 +9,11 @@ cost_analysis() is per-device for SPMD executables (verified empirically:
 a (256,512)x(512,1024) matmul over 8 devices reports 2MNK/8 flops), so the
 per-device forms above equal the spec's global/(chips*rate) forms.
 
-Hardware constants (TPU v5e-class, per assignment): 197 TFLOP/s bf16,
-819 GB/s HBM, ~50 GB/s/link ICI.  int8 MXU peak is 2x bf16 — both fractions
-are reported; the headline roofline fraction uses the bf16 constant per the
-assignment, the int8 column shows what the WAGEUBN datapath unlocks.
+Hardware constants come from PEAKS, one row per jax `device_kind`; a kind
+missing from the table raises rather than borrowing another chip's peaks.
+The dry-run artifacts model a v5e (TARGET_KIND).  Both MXU fractions are
+reported; the headline roofline fraction uses the bf16 peak, the int8
+column shows what the WAGEUBN datapath unlocks.
 """
 from __future__ import annotations
 
@@ -22,10 +23,23 @@ import json
 import os
 import re
 
-PEAK_BF16 = 197e12
-PEAK_INT8 = 394e12
-HBM_BW = 819e9
-LINK_BW = 50e9
+# Published per-chip peaks, keyed by jax's device_kind.  Source: Google
+# Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 393 TOP/s int8, HBM at
+# 819 GB/s, 1,600 Gbit/s (200 GB/s) of inter-chip interconnect.
+PEAKS = {
+    "TPU v5 lite": {"bf16": 197e12, "int8": 393e12, "hbm": 819e9,
+                    "ici": 200e9},
+}
+TARGET_KIND = "TPU v5 lite"
+
+
+def peaks(kind: str) -> dict:
+    """The PEAKS row for `kind`; an unknown device kind is an error."""
+    if kind not in PEAKS:
+        raise ValueError(f"no published peaks for device kind {kind!r} "
+                         f"(known: {sorted(PEAKS)})")
+    return PEAKS[kind]
+
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "f16": 2, "bf16": 2,
@@ -104,15 +118,16 @@ def parse_collectives(hlo_text: str) -> dict:
     return out
 
 
-def terms(art: dict) -> dict:
+def terms(art: dict, kind: str = TARGET_KIND) -> dict:
     """Roofline terms (seconds) + fractions for one artifact dict."""
+    pk = peaks(kind)
     flops = art["flops_per_device"]
     mem_bytes = art["bytes_per_device"]
     coll_bytes = art["collective_bytes_per_device"]
-    t_c = flops / PEAK_BF16
-    t_c8 = flops / PEAK_INT8
-    t_m = mem_bytes / HBM_BW
-    t_l = coll_bytes / LINK_BW
+    t_c = flops / pk["bf16"]
+    t_c8 = flops / pk["int8"]
+    t_m = mem_bytes / pk["hbm"]
+    t_l = coll_bytes / pk["ici"]
     dominant = max(("compute", t_c), ("memory", t_m),
                    ("collective", t_l), key=lambda kv: kv[1])[0]
     total = max(t_c, t_m, t_l)
@@ -129,23 +144,21 @@ def terms(art: dict) -> dict:
 
 
 def measured_fraction(flops: float, mem_bytes: float, dt_s: float,
-                      coll_bytes: float = 0.0) -> dict:
-    """%-of-roofline for a MEASURED step time (the bench harness hook).
+                      coll_bytes: float = 0.0, *, kind: str) -> dict:
+    """%-of-roofline for a step time MEASURED on a device of `kind`.
 
-    The roofline floor is max(compute, memory, collective) seconds at the
-    reference chip's peaks; the fraction is floor / measured.  Reported at
-    BOTH MXU peaks — "pct_bf16" (f32/bf16 peak) and "pct_int8" (the 2x
-    int8 peak the paper's data paths target): a fused-int8 step that looks
-    healthy against the bf16 peak but poor against the int8 peak is
-    leaving the MXU's 2x on the table, which is exactly the regression
-    this field exists to attribute.  On the CPU CI container the absolute
-    fractions are tiny (the constants model a TPU chip) — the signal is
-    their trajectory between commits, not their magnitude.
+    The roofline floor is max(compute, memory, collective) seconds at that
+    chip's peaks; the fraction is floor / measured.  Reported at BOTH MXU
+    peaks — "pct_bf16" (f32/bf16 peak) and "pct_int8" (the int8 peak the
+    paper's data paths target): a fused-int8 step that looks healthy
+    against the bf16 peak but poor against the int8 peak is leaving the
+    MXU's 2x on the table.
     """
-    t_m = mem_bytes / HBM_BW
-    t_l = coll_bytes / LINK_BW
+    pk = peaks(kind)
+    t_m = mem_bytes / pk["hbm"]
+    t_l = coll_bytes / pk["ici"]
     out = {}
-    for tag, peak in (("pct_bf16", PEAK_BF16), ("pct_int8", PEAK_INT8)):
+    for tag, peak in (("pct_bf16", pk["bf16"]), ("pct_int8", pk["int8"])):
         floor = max(flops / peak, t_m, t_l)
         out[tag] = (floor / dt_s) if dt_s > 0 else 0.0
     return out

@@ -34,6 +34,17 @@ from repro.optim import (apply_leaf_update, dr_bits_schedule, fixed_point_lr,
 SEED = 17
 
 
+def make_task(acfg, batch: int, seq: int):
+    """The seeded synthetic task for `acfg`'s family: images of the
+    configured size and class count for the resnet family (`seq` unused),
+    token rows otherwise."""
+    from repro.data import ImageTask, TokenTask
+    if acfg.family == "resnet":
+        return ImageTask(img_size=acfg.img_size,
+                         num_classes=acfg.num_classes, global_batch=batch)
+    return TokenTask(vocab=acfg.vocab, seq_len=seq, global_batch=batch)
+
+
 def make_train_step(model, qcfg, labels_tree, lr=0.05, mom=0.75,
                     dr_bits: int | None = None, n_micro: int = 1):
     """n_micro > 1 accumulates gradients over microbatches (lax.scan) —
@@ -183,8 +194,6 @@ def make_sharded_train_step(model, qcfg, labels_tree, mesh, params, *,
     integer sum — so weights after the step are a pure function of
     (global batch, n_shards), not of the device layout.
     """
-    from repro.compat import SHARD_MAP_KW as _SM_KW
-    from repro.compat import shard_map as _shard_map
     from repro.launch import shard as S
     from repro.runtime.compress import (default_wire_codec, wire_sync_mean,
                                         wire_sync_tree)
@@ -255,12 +264,12 @@ def make_sharded_train_step(model, qcfg, labels_tree, mesh, params, *,
               else S.opt_specs(pspecs))
     # zero1 implies tp == 1, where pspecs is already the all-replicated
     # tree — params come back replicated either way
-    step_fn = _shard_map(
+    step_fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(pspecs, ospecs, jax.sharding.PartitionSpec("data"),
                   jax.sharding.PartitionSpec()),
         out_specs=(pspecs, ospecs, jax.sharding.PartitionSpec()),
-        **_SM_KW)
+        check_vma=False)
     specs = {"params": pspecs, "opt": ospecs,
              "batch": jax.sharding.PartitionSpec("data")}
     return step_fn, specs
@@ -401,8 +410,6 @@ def tp_serving_wrap(fn, mesh, in_specs, out_specs):
     tp_int_wire (tp_exit reductions ride integer all_gathers).  The
     contexts are entered inside the body, so every retrace re-applies
     them; at trace time they cost nothing when tp == 1."""
-    from repro.compat import SHARD_MAP_KW as _SM_KW
-    from repro.compat import shard_map as _shard_map
     from repro.core import qfuncs as qf
     from repro.models import layers as mlayers
 
@@ -412,8 +419,8 @@ def tp_serving_wrap(fn, mesh, in_specs, out_specs):
         with qf.amax_sync(S.MODEL_AXIS), mlayers.tp_int_wire():
             return fn(*args)
 
-    return _shard_map(body, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **_SM_KW)
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def make_prefill(model, shape_name):
@@ -449,7 +456,9 @@ def main(argv=None):
     p.add_argument("--mode", default="sim", choices=["fp32", "sim", "native"])
     p.add_argument("--steps", type=int, default=50)
     p.add_argument("--batch", type=int, default=8)
-    p.add_argument("--seq", type=int, default=64)
+    p.add_argument("--seq", type=int, default=64,
+                   help="token sequence length (ignored by the resnet "
+                        "family, which trains on images)")
     p.add_argument("--lr", type=float, default=0.05)
     p.add_argument("--reduced", action="store_true",
                    help="use the reduced smoke config (CPU scale)")
@@ -496,6 +505,8 @@ def main(argv=None):
                    help="elastic: shrink dp to the next divisor of n_shards "
                         "after this many straggler flags (0 = off)")
     args = p.parse_args(argv)
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache()
 
     acfg = get_arch(args.arch)
     if args.reduced:
@@ -514,9 +525,7 @@ def main(argv=None):
     sharded = args.dp * args.tp > 1
     model = build_model(acfg, qcfg, tp_size=args.tp if sharded else 1)
 
-    from repro.data import TokenTask
-    task = TokenTask(vocab=acfg.vocab, seq_len=args.seq,
-                     global_batch=args.batch)
+    task = make_task(acfg, args.batch, args.seq)
 
     key = jax.random.PRNGKey(0)
     params = model.init(key)

@@ -254,11 +254,7 @@ def chunked_attention(cfg: QConfig, q: Array, k: Array, v: Array, *,
     the unfused body (the per-chunk qeinsum Q_E2 semantics of Alg. 2 are
     unchanged).  Everything else takes the pure-JAX chunked path.
     """
-    if (cfg.native and cfg.fuse_kernels
-            and all(map(_payload8, (q, k, v)))
-            and kops.flash_attention_fits(
-                q.shape[0], min(q_chunk, q.shape[1]), q.shape[2],
-                q.shape[3])):
+    if cfg.native and cfg.fuse_kernels and all(map(_payload8, (q, k, v))):
         out = _flash_fused(cfg, causal, min(q_chunk, q.shape[1]),
                            min(kv_chunk, k.shape[1]), q, k, v, q_pos, k_pos)
         return qact(cfg, "none", out)
@@ -429,9 +425,7 @@ def paged_decode_attention(cfg: QConfig, q, k_pages, v_pages, table, k_scale,
     to end: the paged cache is never dequantized or concatenated in fp32.
     """
     b, s, h, dh = q.shape
-    if (cfg.native and cfg.fuse_kernels and s == 1 and _payload8(q)
-            and kops.paged_attention_fits(h, table.shape[1]
-                                          * k_pages.shape[1])):
+    if cfg.native and cfg.fuse_kernels and s == 1 and _payload8(q):
         out = kops.paged_attention_op(
             q.data.reshape(b, h, dh), k_pages, v_pages, table, q_pos,
             t_valid, q.scale, k_scale, v_scale,

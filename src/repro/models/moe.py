@@ -21,8 +21,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import SHARD_MAP_KW as _SM_KW
-from repro.compat import shard_map as _shard_map
 
 from repro.core import qact, qeinsum, qt_carrier, qweight
 from repro.core.qconfig import QConfig
@@ -150,10 +148,10 @@ def moe_ffn(cfg: QConfig, acfg, x, p, mesh=None, dp_axes=("data",),
                        dropless=dropless)
         return lax.psum(y, tp_axis)
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         f, mesh=mesh,
         in_specs=(P(dp_axes, None), P(None, None), P(tp_axis, None, None),
                   P(tp_axis, None, None), P(tp_axis, None, None)),
-        out_specs=P(dp_axes, None), **_SM_KW)
+        out_specs=P(dp_axes, None), check_vma=False)
     y = fn(x2, p["router"], p["wg"], p["wu"], p["wd"])
     return y.reshape(b, s, d)

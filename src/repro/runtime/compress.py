@@ -57,8 +57,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import SHARD_MAP_KW as _SM_KW
-from repro.compat import shard_map as _shard_map
 from repro.core import qfuncs as qf
 from repro.core.qtensor import QTensor, payload_dtype
 
@@ -248,8 +246,8 @@ def ring_reduce_scatter_int(x, mesh, axis_name: str, bits: int = 16):
         return acc.astype(jnp.float32) * qt.scale / n
 
     spec = P(*((None,) * x.ndim))
-    fn = _shard_map(f, mesh=mesh, in_specs=(spec,),
-                    out_specs=P(axis_name), **_SM_KW)
+    fn = jax.shard_map(f, mesh=mesh, in_specs=(spec,),
+                       out_specs=P(axis_name), check_vma=False)
     return fn(x)
 
 
@@ -276,8 +274,8 @@ def compressed_psum_int(x, mesh, axis_name: str, bits: int = 16):
         return (full.astype(jnp.float32) * qt.scale / n).reshape(shape)
 
     spec = P(*((None,) * x.ndim))
-    fn = _shard_map(f, mesh=mesh, in_specs=(spec,), out_specs=spec,
-                    **_SM_KW)
+    fn = jax.shard_map(f, mesh=mesh, in_specs=(spec,), out_specs=spec,
+                       check_vma=False)
     return fn(x)
 
 

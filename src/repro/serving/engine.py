@@ -787,7 +787,8 @@ def _scatter_pages(pages, pids, chunk):
 def fused_decode_active(engine: Engine) -> bool:
     """Whether the engine's decode step streams KV pages through the fused
     paged-attention kernel (True) or fell back to gather-then-attend
-    (False, e.g. sim mode or `fuse_kernels=False`).
+    (False, e.g. sim mode, `fuse_kernels=False`, or pages shorter than the
+    128 tokens the TPU kernel needs).
 
     Decided from the decode-step jaxpr with the kernel dispatch forced, so
     the route is visible regardless of backend (the CPU oracle of the

@@ -1,5 +1,7 @@
 """Pallas kernels vs pure-jnp oracles: shape/dtype sweeps in interpret mode
 (requirement (c): per-kernel allclose against ref.py)."""
+import collections
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -252,6 +254,25 @@ def test_ubn_sweep(m, n, kind):
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
+# ResNet-50 batch-norm views (rows, channels) at batch 32, 224 px that take
+# the kernel route on a TPU (tests/test_tpu_compile.py compiles them)
+@pytest.mark.parametrize("m,c", [(25088, 128), (25088, 256), (25088, 512),
+                                 (6272, 256), (6272, 512), (6272, 1024),
+                                 (1568, 512), (1568, 2048)])
+def test_ubn_batch_resnet50_shapes_bitexact(m, c):
+    """Through ops.ubn_norm_op's legal tile, the kernel (interpret mode)
+    is bit-identical to the oracle at the paper model's own shapes."""
+    from repro.kernels import ops
+    x = jax.random.normal(jax.random.PRNGKey(0), (m, c)) * 0.5 + 0.1
+    gamma = jax.random.normal(jax.random.PRNGKey(1), (c,)) * 0.2 + 1.0
+    beta = jax.random.normal(jax.random.PRNGKey(2), (c,)) * 0.1
+    assert ops._ubn_tile("batch", m, c) is not None
+    got = ops.ubn_norm_op(x, gamma, beta, kind="batch", force_kernel=True,
+                          **_UBN_W)
+    want = ref.ubn_norm_ref(x, gamma, beta, kind="batch", **_UBN_W)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
 def test_ubn_zero_rows_no_nan():
     """Padded/degenerate rows (all zeros) must normalize to 0, not NaN."""
     x = jnp.zeros((5, 16))
@@ -483,6 +504,30 @@ def test_chunked_attention_fused_bitexact_and_grads():
                                    rtol=1e-6, atol=1e-7)
 
 
+def test_unaligned_attention_takes_counted_oracle_on_tpu(monkeypatch):
+    """Under TPU dispatch, shapes whose blocks break the TPU tiling rule
+    (4-token pages, 16-wide kv chunks) take the bit-identical oracle, and
+    ops.ORACLE_ON_TPU counts each such traced call."""
+    from repro.kernels import ops
+    q8, kp, vp, table, q_pos, t_valid = _paged_case(9, 4, 2, 2, 8, 3, 4)
+    scal = (jnp.float32(2 ** -6), jnp.float32(2 ** -7), jnp.float32(2 ** -7))
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    monkeypatch.setattr(ops, "ORACLE_ON_TPU", collections.Counter())
+    jaxpr = jax.make_jaxpr(lambda q: ops.paged_attention_op(
+        q, kp, vp, table, q_pos, t_valid, *scal, sm_scale=0.125))(q8)
+    assert "pallas_call" not in str(jaxpr)
+    qf8 = jnp.zeros((1, 32, 4, 16), jnp.int8)
+    kf8 = jnp.zeros((1, 32, 2, 16), jnp.int8)
+    pos = jnp.arange(32)
+    jaxpr = jax.make_jaxpr(lambda q: ops.flash_attention_op(
+        q, kf8, kf8, pos, pos, jnp.ones((32,), jnp.int32), *scal,
+        causal=True, sm_scale=0.25, q_chunk=16, kv_chunk=16))(qf8)
+    assert "pallas_call" not in str(jaxpr)
+    assert ops.ORACLE_ON_TPU == {"paged_attention": 1, "flash_attention": 1}
+    assert "oracle_on_tpu=flash_attention:1,paged_attention:1" in \
+        ops.dispatch_banner()
+
+
 def test_fused_decode_jaxpr_streams_pages():
     """Acceptance: with the kernel dispatch forced, the fused decode trace
     contains NO standalone page-gather result and NO dense (B, T, ...) KV
@@ -492,7 +537,8 @@ def test_fused_decode_jaxpr_streams_pages():
     from repro.core.qtensor import QTensor
     from repro.kernels import ops
     from repro.models import layers as L
-    q8, kp, vp, table, q_pos, t_valid = _paged_case(9, 4, 2, 2, 8, 3, 4)
+    # 128-token pages: the TPU kernel route needs lane-aligned pages
+    q8, kp, vp, table, q_pos, t_valid = _paged_case(9, 128, 2, 2, 8, 3, 4)
     b, h, dh = q8.shape
     page, kv = kp.shape[1], kp.shape[2]
     nb = table.shape[1]

@@ -1,5 +1,7 @@
 """Roofline: collective parser on canned HLO + term arithmetic."""
-from repro.launch.roofline import parse_collectives, terms
+import pytest
+
+from repro.launch.roofline import PEAKS, parse_collectives, peaks, terms
 
 CANNED = """
 HloModule jit_f, num_partitions=8
@@ -35,7 +37,7 @@ def test_terms_dominance():
     art = {
         "flops_per_device": 197e12,      # exactly 1 s of bf16 compute
         "bytes_per_device": 819e9 / 2,   # 0.5 s of HBM
-        "collective_bytes_per_device": 50e9 / 4,  # 0.25 s of ICI
+        "collective_bytes_per_device": 200e9 / 4,  # 0.25 s of ICI
         "devices": 256,
         "model_flops_global": 197e12 * 256 * 0.8,
     }
@@ -46,7 +48,7 @@ def test_terms_dominance():
     assert abs(t["collective_s"] - 0.25) < 1e-9
     assert abs(t["roofline_fraction"] - 1.0) < 1e-9
     assert abs(t["useful_ratio"] - 0.8) < 1e-9
-    assert abs(t["compute_int8_s"] - 0.5) < 1e-9
+    assert abs(t["compute_int8_s"] - 197e12 / 393e12) < 1e-9
 
 
 def test_terms_memory_bound():
@@ -56,3 +58,13 @@ def test_terms_memory_bound():
     t = terms(art)
     assert t["dominant"] == "memory"
     assert t["roofline_fraction"] < 0.01
+
+
+def test_peaks_table_is_published_v5e_and_unknown_kind_raises():
+    assert PEAKS["TPU v5 lite"] == {"bf16": 197e12, "int8": 393e12,
+                                    "hbm": 819e9, "ici": 200e9}
+    with pytest.raises(ValueError, match="no published peaks"):
+        peaks("cpu")
+    with pytest.raises(ValueError):
+        terms({"flops_per_device": 1.0, "bytes_per_device": 1.0,
+               "collective_bytes_per_device": 0.0, "devices": 1}, kind="cpu")
