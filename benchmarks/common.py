@@ -187,32 +187,6 @@ def measure(call, *, warmup: int = 2, min_steps: int | None = None,
             return mu, cv, len(ts)
 
 
-def step_cost(jitted, *args) -> dict:
-    """flops + HBM bytes of a jitted callable at `args`, from the compiled
-    computation's cost_analysis (per device under SPMD).  A backend that
-    reports no analysis gives zeros; a compile error propagates.
-    """
-    ca = jitted.lower(*args).compile().cost_analysis() or {}
-    return {"flops": float(ca.get("flops", 0.0)),
-            "bytes": float(ca.get("bytes accessed", 0.0))}
-
-
-def roofline_derived(cost: dict, dt_s: float, coll_bytes: float = 0.0) -> str:
-    """`derived`-field fragment: %-of-roofline at the bf16 AND int8 peaks
-    (launch/roofline.measured_fraction) for a timed row.  Off a TPU there
-    is no device time to divide, so both read `not_measured`."""
-    from repro.launch.roofline import measured_fraction
-
-    dev = jax.devices()[0]
-    if dev.platform != "tpu":
-        return ("%_of_roofline_bf16=not_measured;"
-                "%_of_roofline_int8=not_measured")
-    fr = measured_fraction(cost.get("flops", 0.0), cost.get("bytes", 0.0),
-                           dt_s, coll_bytes, kind=dev.device_kind)
-    return (f"%_of_roofline_bf16={fr['pct_bf16'] * 100:.4f};"
-            f"%_of_roofline_int8={fr['pct_int8'] * 100:.4f}")
-
-
 # rows emitted since the last take_records() — benchmarks.run snapshots
 # these into the append-style BENCH_<suite>.json trajectory files
 RECORDS: list[dict] = []

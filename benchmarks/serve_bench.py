@@ -43,28 +43,9 @@ from __future__ import annotations
 
 import os
 
-from .common import emit, roofline_derived, step_cost
+from .common import emit
 
 ARCH = "granite-3-8b"
-
-
-def _decode_cost(eng) -> dict:
-    """flops/bytes of the engine's fused decode step at its exact shapes
-    (same fresh-wrapper trick as Engine.decode_jaxpr: never share the live
-    _decode_jit's tracing cache)."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    slots = dict(eng.slots, pos=jnp.zeros((eng.max_lanes,), jnp.int32))
-    if eng.paged:
-        kp, vp = eng.pool.k, eng.pool.v
-    else:
-        kp = jnp.zeros((0,), jnp.int8)
-        vp = jnp.zeros((0,), jnp.int8)
-    fn = jax.jit(lambda *a: eng._decode_step(*a))
-    return step_cost(fn, eng.params, slots, kp, vp, jnp.asarray(eng.table),
-                     jnp.asarray(eng.h_tokens), np.int32(0))
 
 
 def _measure_decode(engine, n_lanes: int, prompt_len: int, max_new: int):
@@ -115,10 +96,8 @@ def _fused_vs_unfused(ctxs, fast: bool):
                 us[label] = min(us.get(label, t), t)
         for fused in engines:
             label = "fused" if fused else "unfused"
-            cost = _decode_cost(engines[fused])
             emit(f"serve/{label}_ctx{ctx}", us[label],
-                 f"steps={steps};reps={n_rep};fused_active={fused};"
-                 + roofline_derived(cost, us[label] / 1e6))
+                 f"steps={steps};reps={n_rep};fused_active={fused}")
         ratio_at_largest = us["unfused"] / max(us["fused"], 1e-9)
     emit("serve/decode_fusion", 0.0,
          f"fused_vs_unfused={ratio_at_largest:.2f}x;ctx={ctxs[-1]}")

@@ -7,8 +7,7 @@ bit-exact, so this isolates the data-movement win of fusing Q_E2 into the
 matmul prologues and the five UBN quantizers into one pass).
 
 CSV rows (name,us_per_call,derived — `derived` is ';'-separated):
-  train/<config>_fused    — us per training step; tokens/s; %_of_roofline
-                            at the bf16 and int8 peaks (common.measure
+  train/<config>_fused    — us per training step; tokens/s (common.measure
                             warmup-corrected CV-guarded timing throughout)
   train/<config>_unfused  — same, fuse_kernels=False
   train/<config>_speedup  — fused-vs-unfused step-time ratio
@@ -44,7 +43,7 @@ import subprocess
 import sys
 import time
 
-from .common import emit, measure, roofline_derived, step_cost
+from .common import emit, measure
 
 
 def _configs(fast: bool):
@@ -104,11 +103,9 @@ def main():
             step_fn = jax.jit(
                 make_train_step(model, qcfg, model.labels(params)))
             dt, cv, n = _time_steps(step_fn, params, opt, batch)
-            cost = step_cost(step_fn, params, opt, batch, jnp.int32(0))
             step_us[label] = dt * 1e6
             emit(f"train/{name}_{label}", dt * 1e6,
-                 f"tok_s={tokens / dt:.1f};steps={n};cv={cv:.3f};"
-                 + roofline_derived(cost, dt))
+                 f"tok_s={tokens / dt:.1f};steps={n};cv={cv:.3f}")
         emit(f"train/{name}_speedup", 0.0,
              f"fused_vs_unfused={step_us['unfused'] / step_us['fused']:.2f}x")
     _ckpt_bench(fast)
@@ -257,18 +254,16 @@ def _dp_rows(row):
         params = S.shard_arrays(mesh, params, specs["params"])
         opt = S.shard_arrays(mesh, opt, specs["opt"])
         batch = S.put_batch(mesh, task.batch(0))
-        dt, cv, n = _time_steps(step_fn, params, opt, batch)
-        cost = step_cost(step_fn, params, opt, batch, jnp.int32(0))
-        return dt, cv, n, cost
+        return _time_steps(step_fn, params, opt, batch)
 
     base_us = {}
     for dp in (1, 2, 4):
         for sync, tag in (("int_ring", "intwire"), ("psum", "f32wire")):
-            dt, cv, n, cost = run(dp, sync)
+            dt, cv, n = run(dp, sync)
             base_us[(dp, tag)] = dt * 1e6
             row(f"train/dp{dp}_{tag}", dt * 1e6,
                 f"tok_s={tokens / dt:.1f};steps={n};cv={cv:.3f};"
-                f"arch={name};" + roofline_derived(cost, dt))
+                f"arch={name}")
     ratio = base_us[(1, 'intwire')] / base_us[(4, 'intwire')]
     wire = base_us[(4, 'f32wire')] / base_us[(4, 'intwire')]
     row("train/dp_scaling", 0.0,
@@ -278,8 +273,8 @@ def _dp_rows(row):
     # two-per-int16 hops) vs the per-leaf unpacked rings — bit-identical
     # weights, different wires.  Message elements come from the traced
     # jaxpr (per hop: every ppermute eqn fires each of the n-1 hops).
-    dt_p, _, _, _ = run(2, "int_ring", codec="packed", wire_bits=8)
-    dt_u, _, _, _ = run(2, "int_ring", codec="leaf", wire_bits=8)
+    dt_p, _, _ = run(2, "int_ring", codec="packed", wire_bits=8)
+    dt_u, _, _ = run(2, "int_ring", codec="leaf", wire_bits=8)
 
     def hop_elems(codec):
         mesh = make_cpu_mesh(2, 1)

@@ -60,9 +60,10 @@ def qweight(cfg: QConfig, w: Array):
     if not cfg.quantize or not cfg.quant_w:
         return w
     quantizer = cfg.w.make()
-    if cfg.native:
-        return quantize_ste(quantizer, w)
-    return qf.ste(quantizer, w)
+    with jax.named_scope("qweight"):
+        if cfg.native:
+            return quantize_ste(quantizer, w)
+        return qf.ste(quantizer, w)
 
 
 def qbn_param(cfg: QConfig, p: Array, k: int) -> Array:
@@ -93,7 +94,8 @@ _ACT = {
 def qact(cfg: QConfig, act: str, x):
     """activation + Q_A.  Native mode returns a QTensor (the int8 payload is
     what downstream matmuls consume); sim/fp32 return fp32 carriers."""
-    return _qact(cfg, act, qt_carrier(x))
+    with jax.named_scope("qact"):
+        return _qact(cfg, act, qt_carrier(x))
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(0, 1))
@@ -116,7 +118,8 @@ def _qact_bwd(cfg, act, x, ct):
     _, dfn = _ACT[act]
     g = ct.carrier if isinstance(ct, QTensor) else ct
     if cfg.quantize and cfg.quant_e1:
-        g = cfg.e1.make()(g)          # Q_E1: e0 = SQ(e4^{l+1})   (Eq. 15)
+        with jax.named_scope("q_e1"):
+            g = cfg.e1.make()(g)      # Q_E1: e0 = SQ(e4^{l+1})   (Eq. 15)
     return (g * dfn(x),)              # e1 = e0 * dACT            (Alg. 2)
 
 
@@ -386,9 +389,11 @@ def qconv(cfg: QConfig, x, wq, stride: int, padding: str) -> Array:
     Conv arithmetic runs on exact grid values in fp32 (integer-identical;
     see DESIGN.md §3 — XLA's int8 conv path is TPU-only, so the carrier is
     fp32 while the *semantics* are fixed-point).  QTensor operands
-    contribute their differentiable carriers.
+    contribute their differentiable carriers.  The backward rule's ops
+    inherit the "qconv" scope (as `transpose(jvp(qconv))`).
     """
-    return _qconv(cfg, qt_carrier(x), qt_carrier(wq), stride, padding)
+    with jax.named_scope("qconv"):
+        return _qconv(cfg, qt_carrier(x), qt_carrier(wq), stride, padding)
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(0, 3, 4))
@@ -404,21 +409,22 @@ def _qconv_fwd(cfg, x, wq, stride, padding):
 
 def _qconv_bwd(cfg, stride, padding, vjp, g):
     if cfg.quantize and cfg.quant_e2:
-        quantizer = cfg.e2.make()
-        plan = (quantizer.fused_plan(g)
-                if cfg.native and cfg.fuse_kernels else None)
-        if plan is not None and plan[0] == "affine" and plan[2] <= 8 \
-                and quantizer.name != "none":
-            # single-plane int8 formats decompose through the fused
-            # quantize kernel dispatch (quantize_op), so e3 materializes
-            # once as its int8 payload; the conv vjp consumes the grid
-            # value (== the legacy fp32 formula bit-exactly, per the
-            # registry invariant).  Multi-plane (flag) and wide formats
-            # keep the one-pass legacy formula — decomposing them here
-            # would add passes, not remove them.
-            g = quantizer.quantize(g).dequantize()
-        else:
-            g = quantizer(g)           # e3 = Q_E2(...)
+        with jax.named_scope("q_e2"):
+            quantizer = cfg.e2.make()
+            plan = (quantizer.fused_plan(g)
+                    if cfg.native and cfg.fuse_kernels else None)
+            if plan is not None and plan[0] == "affine" and plan[2] <= 8 \
+                    and quantizer.name != "none":
+                # single-plane int8 formats decompose through the fused
+                # quantize kernel dispatch (quantize_op), so e3 materializes
+                # once as its int8 payload; the conv vjp consumes the grid
+                # value (== the legacy fp32 formula bit-exactly, per the
+                # registry invariant).  Multi-plane (flag) and wide formats
+                # keep the one-pass legacy formula — decomposing them here
+                # would add passes, not remove them.
+                g = quantizer.quantize(g).dequantize()
+            else:
+                g = quantizer(g)           # e3 = Q_E2(...)
     return vjp(g)
 
 

@@ -63,10 +63,13 @@ def amax_sync(axis: str | None):
 
 
 def amax(x: Array) -> Array:
-    m = jnp.max(jnp.abs(x))
-    if _AMAX_SYNC_AXIS is not None:
-        m = jax.lax.pmax(m, _AMAX_SYNC_AXIS)
-    return m
+    """max|x|, the one reduction in front of every layer-wise quantizer
+    (pmaxed over the `amax_sync` axis when one is set)."""
+    with jax.named_scope("amax"):
+        m = jnp.max(jnp.abs(x))
+        if _AMAX_SYNC_AXIS is not None:
+            m = jax.lax.pmax(m, _AMAX_SYNC_AXIS)
+        return m
 
 
 def pow2_round(m: Array) -> Array:

@@ -163,22 +163,25 @@ _fused_rmsnorm.defvjp(_fused_rmsnorm_fwd, _fused_rmsnorm_bwd)
 def qbatchnorm(cfg: QConfig, x, gamma: Array, beta: Array) -> Array:
     """Quantized BN over all axes but the last (channel), paper Eq. 12."""
     x = qt_carrier(x)
-    if _fuse(cfg):
-        return _fused_norm("batch", cfg, x, gamma, beta)
-    return _qbatchnorm_unfused(cfg, x, gamma, beta)
+    with jax.named_scope("ubn"):
+        if _fuse(cfg):
+            return _fused_norm("batch", cfg, x, gamma, beta)
+        return _qbatchnorm_unfused(cfg, x, gamma, beta)
 
 
 def qrmsnorm(cfg: QConfig, x, gamma: Array) -> Array:
     """Quantized RMSNorm: the BN recipe with per-token stats, no mean."""
     x = qt_carrier(x)
-    if _fuse(cfg):
-        return _fused_rmsnorm(cfg, x, gamma)
-    return _qrmsnorm_unfused(cfg, x, gamma)
+    with jax.named_scope("ubn"):
+        if _fuse(cfg):
+            return _fused_rmsnorm(cfg, x, gamma)
+        return _qrmsnorm_unfused(cfg, x, gamma)
 
 
 def qlayernorm(cfg: QConfig, x, gamma: Array, beta: Array) -> Array:
     """Quantized LayerNorm (per-token mean + var), same widths as BN."""
     x = qt_carrier(x)
-    if _fuse(cfg):
-        return _fused_norm("layer", cfg, x, gamma, beta)
-    return _qlayernorm_unfused(cfg, x, gamma, beta)
+    with jax.named_scope("ubn"):
+        if _fuse(cfg):
+            return _fused_norm("layer", cfg, x, gamma, beta)
+        return _qlayernorm_unfused(cfg, x, gamma, beta)

@@ -85,7 +85,7 @@ def _bwd_kernel(g_ref, b_ref, s_ref, o_ref, acc1, acc2, *, mode, k, dgrad):
 
 
 def _bwd_call(g, other, scal, out_shape, specs, out_spec, grid, *,
-              mode, k, dgrad, interpret):
+              mode, k, dgrad, name, interpret):
     two = mode == "flag"
     bo = out_spec.block_shape
     scratch = [pltpu.VMEM(bo, jnp.int32) for _ in range(2 if two else 1)]
@@ -103,6 +103,7 @@ def _bwd_call(g, other, scal, out_shape, specs, out_spec, grid, *,
         scratch_shapes=scratch,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name=name,
         interpret=interpret,
     )(g, other, scal.reshape(1, 3))
 
@@ -133,7 +134,8 @@ def bwd_dgrad(g: jax.Array, b8: jax.Array, scal: jax.Array, *, mode: str,
              pl.BlockSpec((1, 3), lambda i, j, l: (0, 0))]
     out_spec = pl.BlockSpec((bm, bk), lambda i, j, l: (i, j))
     out = _bwd_call(g, b8, scal, (m + pm, kk + pk), specs, out_spec, grid,
-                    mode=mode, k=k, dgrad=True, interpret=interpret)
+                    mode=mode, k=k, dgrad=True, name="bwd_dgrad",
+                    interpret=interpret)
     return out[:m, :kk]
 
 
@@ -162,5 +164,6 @@ def bwd_wgrad(a8: jax.Array, g: jax.Array, scal: jax.Array, *, mode: str,
              pl.BlockSpec((1, 3), lambda i, j, l: (0, 0))]
     out_spec = pl.BlockSpec((bk, bn), lambda i, j, l: (i, j))
     out = _bwd_call(g, a8, scal, (kk + pk, n + pn), specs, out_spec, grid,
-                    mode=mode, k=k, dgrad=False, interpret=interpret)
+                    mode=mode, k=k, dgrad=False, name="bwd_wgrad",
+                    interpret=interpret)
     return out[:kk, :n]

@@ -49,5 +49,6 @@ def page_gather(pages: jax.Array, table: jax.Array, *,
         _gather_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, nb, page, d), pages.dtype),
+        name="page_gather",
         interpret=interpret,
     )(table, pages)

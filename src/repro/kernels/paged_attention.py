@@ -166,6 +166,7 @@ def paged_attention(q8: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         ),
         out_shape=[jax.ShapeDtypeStruct((b, kvg, 1), jnp.float32)] * 2,
         compiler_params=params,
+        name="paged_attention",
         interpret=interpret,
     )(table, qpos, tval, q8, k_pages, kq)
 
@@ -191,6 +192,7 @@ def paged_attention(q8: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         ),
         out_shape=jax.ShapeDtypeStruct((b, kvg, dh), jnp.float32),
         compiler_params=params,
+        name="paged_attention",
         interpret=interpret,
     )(table, qpos, tval, q8, k_pages, v_pages, kq, m, l, pinv, pv)
 
@@ -308,6 +310,7 @@ def flash_attention(q8: jax.Array, k8: jax.Array, v8: jax.Array,
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=tuple(ds), vmem_limit_bytes=FLASH_VMEM_LIMIT),
+        name="flash_attention",
         interpret=interpret,
     )(qh, kh, vh, qpos, kpos, kval, *scal)
     return out.transpose(0, 2, 1, 3)

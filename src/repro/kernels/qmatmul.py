@@ -104,6 +104,7 @@ def qmatmul(a8: jax.Array, b8: jax.Array, requant_inv: jax.Array | None = None,
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="qmatmul",
         interpret=interpret,
     )(*operands)
     return out[:m, :n]

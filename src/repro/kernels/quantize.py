@@ -45,6 +45,7 @@ def quantize_fused(x: jax.Array, inv_step: jax.Array, *, lim: float = 127.0,
                   pl.BlockSpec((1, 1), lambda i, j: (0, 0))],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m + pm, n + pn), jnp.int8),
+        name="quantize_fused",
         interpret=interpret,
     )(x, inv_step.reshape(1, 1))
     return out[:m, :n]
@@ -83,6 +84,7 @@ def cq_stochastic(x: jax.Array, bits: jax.Array, inv_step: jax.Array, *,
                   pl.BlockSpec((1, 1), lambda i, j: (0, 0))],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m + pm, n + pn), jnp.int16),
+        name="cq_stochastic",
         interpret=interpret,
     )(x, bits, inv_step.reshape(1, 1))
     return out[:m, :n]

@@ -66,6 +66,7 @@ def selective_scan(a: jax.Array, b: jax.Array, c: jax.Array, *,
         scratch_shapes=[pltpu.VMEM((bd, n), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="selective_scan",
         interpret=interpret,
     )(a, b, c)
     return out[:, :s, :d]

@@ -126,6 +126,7 @@ def ubn_norm(x: jax.Array, gamma: jax.Array, beta: jax.Array | None, *,
         out_shape=jax.ShapeDtypeStruct(oshape, jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",), vmem_limit_bytes=VMEM_LIMIT),
+        name="ubn_norm",
         interpret=interpret,
     )(x, gamma, beta)
     return out[:m, :n]

@@ -143,27 +143,6 @@ def terms(art: dict, kind: str = TARGET_KIND) -> dict:
     }
 
 
-def measured_fraction(flops: float, mem_bytes: float, dt_s: float,
-                      coll_bytes: float = 0.0, *, kind: str) -> dict:
-    """%-of-roofline for a step time MEASURED on a device of `kind`.
-
-    The roofline floor is max(compute, memory, collective) seconds at that
-    chip's peaks; the fraction is floor / measured.  Reported at BOTH MXU
-    peaks — "pct_bf16" (f32/bf16 peak) and "pct_int8" (the int8 peak the
-    paper's data paths target): a fused-int8 step that looks healthy
-    against the bf16 peak but poor against the int8 peak is leaving the
-    MXU's 2x on the table.
-    """
-    pk = peaks(kind)
-    t_m = mem_bytes / pk["hbm"]
-    t_l = coll_bytes / pk["ici"]
-    out = {}
-    for tag, peak in (("pct_bf16", pk["bf16"]), ("pct_int8", pk["int8"])):
-        floor = max(flops / peak, t_m, t_l)
-        out[tag] = (floor / dt_s) if dt_s > 0 else 0.0
-    return out
-
-
 def load_artifacts(art_dir: str):
     arts = []
     for f in sorted(glob.glob(os.path.join(art_dir, "*.json"))):

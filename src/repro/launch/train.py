@@ -250,9 +250,10 @@ def make_sharded_train_step(model, qcfg, labels_tree, mesh, params, *,
         loss = lax.pmean(jnp.mean(losses), "data")
         okey = jax.random.fold_in(key, 1)
         if opt_shard == "zero1":
-            params2, opt2 = _zero1_update(
-                qcfg, params, grads, opt_state, labels_tree, okey, lrq, mom,
-                dr_bits, dp)
+            with jax.named_scope("momentum_update"):
+                params2, opt2 = _zero1_update(
+                    qcfg, params, grads, opt_state, labels_tree, okey, lrq,
+                    mom, dr_bits, dp)
         else:
             params2, opt2 = momentum_update(
                 qcfg, params, grads, opt_state, labels_tree, okey, lrq,

@@ -136,31 +136,37 @@ class ResNet:
 
     def forward(self, params, images):
         q = self.q
-        # exempt stem (fp32 conv + BN + relu, no quantizers)
-        x = jax.lax.conv_general_dilated(
-            images, params["stem"], (2, 2), "SAME",
-            dimension_numbers=("NHWC", "HWIO", "NHWC"))
-        from repro.core.qconfig import FP32
-        x = qbatchnorm(FP32, x, params["bn_stem"]["gamma"],
-                       params["bn_stem"]["beta"])
-        x = jax.nn.relu(x)
-        x = jax.lax.reduce_window(x, -jnp.inf, jax.lax.max, (1, 3, 3, 1),
-                                  (1, 2, 2, 1), "SAME")
-        x = qact(q, "none", x)
+        with jax.named_scope("stem"):
+            # exempt stem (fp32 conv + BN + relu, no quantizers)
+            x = jax.lax.conv_general_dilated(
+                images, params["stem"], (2, 2), "SAME",
+                dimension_numbers=("NHWC", "HWIO", "NHWC"))
+            from repro.core.qconfig import FP32
+            x = qbatchnorm(FP32, x, params["bn_stem"]["gamma"],
+                           params["bn_stem"]["beta"])
+            x = jax.nn.relu(x)
+            x = jax.lax.reduce_window(x, -jnp.inf, jax.lax.max, (1, 3, 3, 1),
+                                      (1, 2, 2, 1), "SAME")
+            x = qact(q, "none", x)
         for si, blocks in enumerate(params["stages"]):
             for bi, bp in enumerate(blocks):
                 stride = 2 if (si > 0 and bi == 0) else 1
-                x = self._block(bp, x, stride)
-        x = jnp.mean(qt_carrier(x), axis=(1, 2))
-        return x @ params["fc"] + params["fc_b"]      # exempt last layer
+                with jax.named_scope(f"stage{si}"), \
+                        jax.named_scope(f"block{bi}"):
+                    x = self._block(bp, x, stride)
+        with jax.named_scope("head"):
+            x = jnp.mean(qt_carrier(x), axis=(1, 2))
+            return x @ params["fc"] + params["fc_b"]  # exempt last layer
 
     def loss(self, params, batch, key=None):
         logits = self.forward(params, batch["images"])
         labels = batch["labels"]
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        tgt = jnp.take_along_axis(logits, labels[:, None], axis=-1)[:, 0]
-        loss = jnp.mean(lse - tgt)
-        acc = jnp.mean((jnp.argmax(logits, -1) == labels).astype(jnp.float32))
+        with jax.named_scope("head"):
+            lse = jax.nn.logsumexp(logits, axis=-1)
+            tgt = jnp.take_along_axis(logits, labels[:, None], axis=-1)[:, 0]
+            loss = jnp.mean(lse - tgt)
+            acc = jnp.mean((jnp.argmax(logits, -1) == labels)
+                           .astype(jnp.float32))
         return loss, {"loss": loss, "acc": acc}
 
     def input_specs(self, shape_name=None):

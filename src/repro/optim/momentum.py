@@ -115,15 +115,16 @@ def quantize_grad_leaf(cfg: QConfig, g, lab, key, dr_bits: int | None = None):
         return g
     if dr_bits is None:        # unscheduled callers: cfg.k_gw IS the dr width
         dr_bits = cfg.k_gw
-    if lab == "w":
-        # registry-resolved gradient quantizer (cfg.g names kind, k_gc and
-        # static params); the dr schedule and rounding mode are per-step
-        # parameters injected only when the registered quantizer declares
-        # those fields (i.e. CQ-family kinds)
-        return _grad_quantizer(cfg, dr_bits)(g, key=key)
-    if lab in ("gamma", "beta"):
-        k = cfg.k_ggamma if lab == "gamma" else cfg.k_gbeta
-        return get_quantizer("direct", k)(g)
+    with jax.named_scope("cq"):
+        if lab == "w":
+            # registry-resolved gradient quantizer (cfg.g names kind, k_gc
+            # and static params); the dr schedule and rounding mode are
+            # per-step parameters injected only when the registered
+            # quantizer declares those fields (i.e. CQ-family kinds)
+            return _grad_quantizer(cfg, dr_bits)(g, key=key)
+        if lab in ("gamma", "beta"):
+            k = cfg.k_ggamma if lab == "gamma" else cfg.k_gbeta
+            return get_quantizer("direct", k)(g)
     raise ValueError(f"unknown label {lab!r}")
 
 
@@ -135,17 +136,18 @@ def apply_leaf_update(cfg: QConfig, p, gq, a, lab, lr, mom: float = 0.75):
     aligned chunking of (p, gq, a) — the property the ZeRO-1 sharded update
     in launch/train.py relies on (tests/test_sharded_train.py).
     """
-    if _plain_path(cfg, lab) or not cfg.quant_u:
-        # plain momentum (raw mom coefficient; Table II FP32-update runs)
-        acc = mom * a + gq
-        return p - lr * acc, acc
-    momq = _mom_coeff(cfg, mom)
-    acc_full = momq * qf.q_direct(a, cfg.k_acc) + gq      # Eq. 20
-    acc = qf.q_direct(acc_full, cfg.k_acc)
-    dw = lr * acc_full                                    # Eq. 23
-    q = qf.q_direct(p - dw, cfg.k_wu)                     # k_WU grid
-    lim = 1.0 - 2.0 ** (1 - cfg.k_wu)
-    return jnp.clip(q, -lim, lim), acc
+    with jax.named_scope("update"):
+        if _plain_path(cfg, lab) or not cfg.quant_u:
+            # plain momentum (raw mom coefficient; Table II FP32-update runs)
+            acc = mom * a + gq
+            return p - lr * acc, acc
+        momq = _mom_coeff(cfg, mom)
+        acc_full = momq * qf.q_direct(a, cfg.k_acc) + gq      # Eq. 20
+        acc = qf.q_direct(acc_full, cfg.k_acc)
+        dw = lr * acc_full                                    # Eq. 23
+        q = qf.q_direct(p - dw, cfg.k_wu)                     # k_WU grid
+        lim = 1.0 - 2.0 ** (1 - cfg.k_wu)
+        return jnp.clip(q, -lim, lim), acc
 
 
 def momentum_update(cfg: QConfig, params: Any, grads: Any, state: MomentumState,
@@ -163,12 +165,14 @@ def momentum_update(cfg: QConfig, params: Any, grads: Any, state: MomentumState,
     llist = treedef.flatten_up_to(labels)
 
     new_p, new_a = [], []
-    for i, (p, g, a, lab) in enumerate(zip(leaves, glist, alist, llist)):
-        gq = quantize_grad_leaf(cfg, g, lab, jax.random.fold_in(key, i),
-                                dr_bits)
-        q, acc = apply_leaf_update(cfg, p, gq, a, lab, lr, mom)
-        new_p.append(q)
-        new_a.append(acc)
+    with jax.named_scope("momentum_update"):
+        for i, (p, g, a, lab) in enumerate(zip(leaves, glist, alist,
+                                               llist)):
+            gq = quantize_grad_leaf(cfg, g, lab, jax.random.fold_in(key, i),
+                                    dr_bits)
+            q, acc = apply_leaf_update(cfg, p, gq, a, lab, lr, mom)
+            new_p.append(q)
+            new_a.append(acc)
 
     return (jax.tree.unflatten(treedef, new_p),
             MomentumState(acc=jax.tree.unflatten(treedef, new_a),

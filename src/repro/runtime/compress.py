@@ -362,11 +362,12 @@ def wire_sync_mean(g, axis_name: str, *, n_shards: int, n_dev: int,
     """
     shift = wire_shift(n_shards)
     _, hop_bits = wire_plan(bits, shift)
-    amax = lax.pmax(jnp.max(jnp.abs(g)), axis_name)
-    qt = wire_quantize(g, amax, bits, shift)
-    local = jnp.sum(qt.data.astype(jnp.int32), axis=0)
-    total = ring_allreduce_int(local, axis_name, n_dev, hop_bits)
-    return total.astype(jnp.float32) * qt.scale / n_shards
+    with jax.named_scope("wire"):
+        amax = lax.pmax(jnp.max(jnp.abs(g)), axis_name)
+        qt = wire_quantize(g, amax, bits, shift)
+        local = jnp.sum(qt.data.astype(jnp.int32), axis=0)
+        total = ring_allreduce_int(local, axis_name, n_dev, hop_bits)
+        return total.astype(jnp.float32) * qt.scale / n_shards
 
 
 def wire_sync_tree(grads, axis_name: str, *, n_shards: int, n_dev: int,
@@ -396,29 +397,30 @@ def wire_sync_tree(grads, axis_name: str, *, n_shards: int, n_dev: int,
     leaves, treedef = jax.tree.flatten(grads)
     if not leaves:
         return grads
-    shift = wire_shift(n_shards)
-    _, hop_bits = wire_plan(bits, shift)
-    amax = lax.pmax(
-        jnp.stack([jnp.max(jnp.abs(g)) for g in leaves]), axis_name)
-    presums, scales, shapes = [], [], []
-    for i, g in enumerate(leaves):
-        ps, scale = wire_presum(g, amax[i], bits, shift)
-        presums.append(ps.reshape(-1))
-        scales.append(scale)
-        shapes.append(ps.shape)
-    flat = (jnp.concatenate(presums) if len(presums) > 1 else presums[0])
-    total = ring_allreduce_int(flat, axis_name, n_dev, hop_bits,
-                               pack=(hop_bits <= 8),
-                               buckets=2 if n_dev > 1 else 1)
-    outs, off = [], 0
-    for shape, scale in zip(shapes, scales):
-        size = int(np.prod(shape)) if shape else 1
-        seg = total[off:off + size]
-        # same float expression as wire_sync_mean -> bitwise-equal means
-        outs.append((seg.astype(jnp.float32) * scale
-                     / n_shards).reshape(shape))
-        off += size
-    return jax.tree.unflatten(treedef, outs)
+    with jax.named_scope("wire"):
+        shift = wire_shift(n_shards)
+        _, hop_bits = wire_plan(bits, shift)
+        amax = lax.pmax(
+            jnp.stack([jnp.max(jnp.abs(g)) for g in leaves]), axis_name)
+        presums, scales, shapes = [], [], []
+        for i, g in enumerate(leaves):
+            ps, scale = wire_presum(g, amax[i], bits, shift)
+            presums.append(ps.reshape(-1))
+            scales.append(scale)
+            shapes.append(ps.shape)
+        flat = (jnp.concatenate(presums) if len(presums) > 1 else presums[0])
+        total = ring_allreduce_int(flat, axis_name, n_dev, hop_bits,
+                                   pack=(hop_bits <= 8),
+                                   buckets=2 if n_dev > 1 else 1)
+        outs, off = [], 0
+        for shape, scale in zip(shapes, scales):
+            size = int(np.prod(shape)) if shape else 1
+            seg = total[off:off + size]
+            # same float expression as wire_sync_mean -> bitwise-equal means
+            outs.append((seg.astype(jnp.float32) * scale
+                         / n_shards).reshape(shape))
+            off += size
+        return jax.tree.unflatten(treedef, outs)
 
 
 def default_wire_codec(backend: str | None = None) -> tuple[str, str]:
