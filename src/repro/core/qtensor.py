@@ -233,15 +233,13 @@ def qtensor_cotangent(like: QTensor, d_carrier) -> QTensor:
 def _decompose(x: Array, step, k: int) -> QTensor:
     """Shared payload decomposition: clip(round(x/step)) saturated to the
     signed k-bit range.  int8-width payloads route through the fused Pallas
-    quantize kernel (kernels/ops.quantize_op — TPU kernel, jnp oracle on
-    CPU); wider payloads lower through XLA.  `step` must be a power of two,
-    so the reciprocal multiply is exact."""
+    quantize kernel (kernels/ops.quantize_op — TPU kernel on x's own shape,
+    jnp oracle on CPU); wider payloads lower through XLA.  `step` must be a
+    power of two, so the reciprocal multiply is exact."""
     lim = 2.0 ** (k - 1) - 1.0
     step = jnp.asarray(step, jnp.float32)
     if k <= 8:
-        x2 = x.reshape(1, -1) if x.ndim != 2 else x
-        data = quantize_op(x2, jnp.float32(1.0) / step,
-                           lim=lim).reshape(x.shape)
+        data = quantize_op(x, jnp.float32(1.0) / step, lim=lim)
     else:
         data = jnp.clip(jnp.round(x / step), -lim,
                         lim).astype(payload_dtype(k))

@@ -49,7 +49,12 @@ from .ubn import VMEM_BYTES_PER_ELEM, VMEM_LIMIT, ubn_norm
 # whose amax must pmax); dispatch_report / dispatch_banner surface it, so no
 # oracle route is silent
 ORACLE_ON_TPU: collections.Counter = collections.Counter()
-_ORACLE_LOCK = threading.Lock()      # programs may trace on several threads
+_COUNT_LOCK = threading.Lock()       # programs may trace on several threads
+
+# per lane class, the traced quantize_op calls and their elements: "dense"
+# where the minor dim fills whole 128-lane vregs, "narrow" where it is
+# under 128 or ragged, so lanes are padded (trace-time count, no device code)
+QUANTIZE_VIEWS: collections.Counter = collections.Counter()
 
 
 def _on_tpu() -> bool:
@@ -58,7 +63,7 @@ def _on_tpu() -> bool:
 
 def _oracle(op: str) -> None:
     if _on_tpu():
-        with _ORACLE_LOCK:
+        with _COUNT_LOCK:
             ORACLE_ON_TPU[op] += 1
 
 
@@ -94,12 +99,18 @@ def quantize_op(x, inv_step, lim=127.0, *, force_kernel=False):
     """Fused shift/direct quantize payload emission.
 
     Args:
-      x: (M, N) f32 on/near a fixed-point grid; inv_step: scalar f32 exact
-      pow2 reciprocal of the grid step; lim: clip bound.
+      x: f32 of any shape, on/near a fixed-point grid; inv_step: scalar f32
+      exact pow2 reciprocal of the grid step; lim: clip bound.
 
     Returns:
-      (M, N) int8 payload clip(round(x * inv_step), +-lim).
+      int8 payload clip(round(x * inv_step), +-lim), x's shape.  The kernel
+      runs on x's (rows, minor dim) view; its lane class is counted in
+      QUANTIZE_VIEWS.
     """
+    lanes = "dense" if x.ndim and x.shape[-1] % 128 == 0 else "narrow"
+    with _COUNT_LOCK:
+        QUANTIZE_VIEWS[lanes] += 1
+        QUANTIZE_VIEWS[f"{lanes}_elements"] += x.size
     if _on_tpu():
         return quantize_fused(x, inv_step, lim=lim, interpret=False)
     if force_kernel:
@@ -405,14 +416,16 @@ def dispatch_report(cfg=None) -> dict:
 
     Returns {"backend", "route" ("kernel" on TPU else "oracle"),
     "ops": {name: route}, "oracle_on_tpu": {name: traced calls that took
-    the oracle on TPU so far}}; with a QConfig also "mode" and "fused"
-    (whether native mode routes backward/UBN/attention through the fused
-    ops).
+    the oracle on TPU so far}, "quantize_views": {"dense"/"narrow": traced
+    quantize_op calls of that lane class, "<class>_elements": their
+    elements}}; with a QConfig also "mode" and "fused" (whether native mode
+    routes backward/UBN/attention through the fused ops).
     """
     route = "kernel" if _on_tpu() else "oracle"
     rep = {"backend": jax.default_backend(), "route": route,
            "ops": {name: route for name in OPS},
-           "oracle_on_tpu": dict(ORACLE_ON_TPU)}
+           "oracle_on_tpu": dict(ORACLE_ON_TPU),
+           "quantize_views": dict(QUANTIZE_VIEWS)}
     rep["autotune"] = {"entries": len(autotune.entries()),
                        "dir": autotune.cache_dir()}
     from repro.runtime.compress import default_wire_codec

@@ -1,13 +1,15 @@
 """Pallas kernels vs pure-jnp oracles: shape/dtype sweeps in interpret mode
 (requirement (c): per-kernel allclose against ref.py)."""
 import collections
+import json
+import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import ref
+from repro.kernels import quantize, ref
 from repro.kernels.backward import bwd_dgrad, bwd_wgrad
 from repro.kernels.page_gather import page_gather
 from repro.kernels.qmatmul import qmatmul
@@ -41,13 +43,45 @@ def test_qmatmul_int32_accumulation_no_overflow_in_int8_domain():
     assert int(got[0, 0]) == k * 127 * 127
 
 
-@pytest.mark.parametrize("shape", [(16, 16), (100, 70), (256, 300), (1, 8)])
+# shape -> VMEM budget of a block: small budgets tile the (rows, C) view in
+# both dims and leave ragged last blocks, which Pallas masks
+QUANTIZE_CASES = {
+    (16, 16): 20480, (100, 70): 20480, (256, 300): 40960, (1, 8): 20480,
+    (37,): quantize.BLOCK_BYTES, (): quantize.BLOCK_BYTES,   # ranks 1, 0
+    (3, 70, 300): 20480,
+    (3, 7, 7, 64): quantize.BLOCK_BYTES,      # ResNet stage 3's rows at 64
+    (2, 56, 56, 64): quantize.BLOCK_BYTES,    # a stage 0 activation
+    (3, 3, 64, 128): quantize.BLOCK_BYTES,    # an HWIO weight
+    (5, 7, 7, 64): 40960,                     # ragged leading dims
+    (7, 9, 33, 130): 40960,                   # ragged rows and lanes
+}
+
+
+@pytest.mark.parametrize("shape", list(QUANTIZE_CASES))
 @pytest.mark.parametrize("inv", [128.0, 4.0, 1 / 64.0])
 def test_quantize_sweep(shape, inv):
     x = jax.random.normal(jax.random.PRNGKey(0), shape) * 3
-    got = quantize_fused(x, jnp.float32(inv), bm=64, bn=64, interpret=True)
+    got = quantize_fused(x, jnp.float32(inv),
+                         block_bytes=QUANTIZE_CASES[shape], interpret=True)
     want = ref.quantize_ref(x, jnp.float32(inv), 127.0)
+    assert got.shape == x.shape and got.dtype == jnp.int8
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("m,c,budget,block", [
+    (200704, 64, quantize.BLOCK_BYTES, (6528, 64)),   # 64 lanes pad to 128
+    (4608, 512, quantize.BLOCK_BYTES, (1632, 512)),
+    (392, 2000, quantize.BLOCK_BYTES, (384, 2000)),   # rows tile by 32
+    (1, 12845056, quantize.BLOCK_BYTES, (1, 26112)),  # C tiles by 128
+    (147, 64, quantize.BLOCK_BYTES, (147, 64)),       # one whole block
+])
+def test_quantize_blocks_fit_the_budget(m, c, budget, block):
+    br, bc = quantize.quantize_blocks(m, c, 4, budget)
+    assert (br, bc) == block
+    assert br == m or br % 32 == 0
+    assert bc == c or bc % 128 == 0
+    padded = (-(-br // 8) * 8 * 4 + -(-br // 32) * 32) * (-(-bc // 128) * 128)
+    assert padded <= budget
 
 
 @pytest.mark.parametrize("shape", [(32, 32), (100, 70)])
@@ -526,6 +560,54 @@ def test_unaligned_attention_takes_counted_oracle_on_tpu(monkeypatch):
     assert ops.ORACLE_ON_TPU == {"paged_attention": 1, "flash_attention": 1}
     assert "oracle_on_tpu=flash_attention:1,paged_attention:1" in \
         ops.dispatch_banner()
+
+
+def _resnet18_quantize_calls(batch):
+    """(minor dim, elements) of every Q_A and Q_W call of one ResNet-18
+    forward at 224 px: Q_A after the max-pool and after each block's two
+    ReLUs, Q_W of every conv but the float32 stem (basic blocks 2/2/2/2)."""
+    calls = [(64, batch * 56 * 56 * 64)]
+    h, cin = 56, 64
+    for si, cout in enumerate((64, 128, 256, 512)):
+        for bi in range(2):
+            stride = 2 if si and not bi else 1
+            calls += [(cout, 9 * cin * cout), (cout, 9 * cout * cout)]
+            if stride != 1 or cin != cout:
+                calls.append((cout, cin * cout))
+            h, cin = h // stride, cout
+            calls += [(cout, batch * h * h * cout)] * 2
+    return calls
+
+
+def test_quantize_views_count_resnet18_forward(monkeypatch):
+    """dispatch_report()["quantize_views"] counts one forward's 17 Q_A and
+    19 Q_W calls by lane class: the 64-channel stage 0 is "narrow"."""
+    from repro.configs import get
+    from repro.core import preset
+    from repro.kernels import ops
+    from repro.models import build_model
+    monkeypatch.setattr(ops, "QUANTIZE_VIEWS", collections.Counter())
+    model = build_model(get("resnet18"), preset("full8", "native"))
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    jax.eval_shape(model.forward, params,
+                   jax.ShapeDtypeStruct((8, 224, 224, 3), jnp.float32))
+    calls = _resnet18_quantize_calls(8)
+    assert len(calls) == 17 + 19
+    want = collections.Counter()
+    for c, n in calls:
+        lanes = "dense" if c % 128 == 0 else "narrow"
+        want[lanes] += 1
+        want[f"{lanes}_elements"] += n
+    assert want["narrow"] == 5 + 4
+    assert ops.dispatch_report()["quantize_views"] == dict(want)
+    # the elements quantize_roofline counts are exactly the kernel's
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    monkeypatch.syspath_prepend(root)
+    from bench.work import resnet as work
+    with open(os.path.join(root, "bench", "configs", "resnet18.json")) as f:
+        cfg = json.load(f)
+    assert want["dense_elements"] + want["narrow_elements"] == \
+        work.quantized_elements(cfg, 8)
 
 
 def test_fused_decode_jaxpr_streams_pages():

@@ -9,7 +9,9 @@ so that only the test worker that runs this file loads the TPU library.
 """
 from __future__ import annotations
 
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -97,12 +99,59 @@ def test_bwd_wgrad_compiles(one_chip, mode):
              ((TOK, D), I8), ((TOK, FF), F32), ((3,), F32))
 
 
-@pytest.mark.parametrize("shape", [(TOK, D), (1, 32 * 56 * 56 * 64)],
-                         ids=["matrix", "flattened_activation"])
+# Q_A and Q_W at ResNet-50 batch 64: activations of stages 0-3, a 3x3 and a
+# 1x1 HWIO weight, and a width with ragged lanes
+RESNET50_QUANTIZE = {
+    "s0_act64": (64, 56, 56, 64), "s0_act256": (64, 56, 56, 256),
+    "s1_act512": (64, 28, 28, 512), "s3_act2048": (64, 7, 7, 2048),
+    "w3x3": (3, 3, 512, 512), "w1x1": (1, 1, 1024, 2048),
+    "ragged": (8, 7, 7, 2000)}
+
+
+@pytest.mark.parametrize(
+    "shape", [(TOK, D), (1, 32 * 56 * 56 * 64),
+              *RESNET50_QUANTIZE.values()],
+    ids=["matrix", "flattened_activation", *RESNET50_QUANTIZE])
 def test_quantize_fused_compiles(one_chip, shape):
-    # qtensor._decompose flattens non-2-D activations to one row
     _compile(one_chip, lambda x, s: quantize_fused(x, s, interpret=False),
              (shape, F32), SCALAR)
+
+
+def _hlo_shapes(text):
+    """(opcode, dtype, element count) of every instruction in HLO text."""
+    out = []
+    for m in re.finditer(r"= (\w+)\[([\d,]*)\]\S* ([\w-]+)\(", text):
+        dims = [int(d) for d in m.group(2).split(",") if d]
+        out.append((m.group(3), m.group(1), math.prod(dims)))
+    return out
+
+
+def test_quantize_meets_activation_through_bitcasts(one_chip, monkeypatch):
+    """ReLU -> Q_A (qtensor._decompose) -> dequantize at a ResNet-50 b64
+    stage 0 activation: the kernel's operand and result reach the
+    activation through bitcasts alone, with no relayout (reshape, copy,
+    transpose, while loop or dynamic-update-slice) of its f32 or s8
+    buffer on either side; both stay in HBM (no `S(1)`, VMEM)."""
+    from repro.core.qtensor import get_quantizer
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    shape = (64, 56, 56, 64)
+
+    def f(x):
+        return get_quantizer("grid", 8).quantize(jax.nn.relu(x)).dequantize()
+
+    text = _compile(one_chip, f, (shape, F32))
+    kernel = re.search(r"= s8\[[\d,]+\](\S*) custom-call\(%([\w.-]+)",
+                       text)
+    assert kernel is not None and "S(1)" not in kernel.group(1)
+    operand = re.search(rf"%{re.escape(kernel.group(2))} = (\S+) (\w+)\(",
+                        text)
+    assert operand.group(2) == "bitcast" and "S(1)" not in operand.group(1)
+    n = math.prod(shape)
+    moves = [(op, dt) for op, dt, size in _hlo_shapes(text)
+             if size == n and dt in ("f32", "s8") and op in (
+                 "reshape", "copy", "transpose", "while",
+                 "dynamic-update-slice")]
+    assert moves == []
 
 
 def test_cq_stochastic_compiles(one_chip):
