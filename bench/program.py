@@ -2,20 +2,26 @@
 
 The only module of the benchmark that imports the program (`src/repro`).
 It builds the configuration's model through `repro.models.build_model`
-under the configured preset and mode, and the jitted training step through
+(architecture: `arch_config`, the named `repro.configs` entry with every
+field the configuration file names put in its place), under the configured
+preset and mode, and the jitted training step through
 `repro.launch.train.make_train_step` (dp == 1) or `make_sharded_train_step`
 (dp > 1, over the integer gradient wire).  Parameters come from the
 benchmark; the program only checks that their tree is the one it builds.
 
 The step is lowered under JAX's default matmul precision set to the
-configuration's `float32_precision`: the program gives its float32 first
-conv and last FC no precision of their own, so on a TPU they would run in
-one bfloat16 pass.  The setting reaches every conv and dot of the step that
-names no precision; the quantized convs see only values on grids of at most
-8 significant bits, so their results are the same in either precision.
+configuration's `float32_precision`: the program gives its float32 layers
+(a ResNet's first conv and last FC) no precision of their own, so on a TPU
+they would run in one bfloat16 pass.  The setting reaches every conv and
+dot of the step that names no precision; the quantized ones see only values
+on grids of at most 8 significant bits, so their results are the same in
+either precision.  The batch the step takes is the traffic's (`traffic.py`),
+and the work it is held to is counted by the family's work file,
+`work/<family>.py`.
 """
 from __future__ import annotations
 
+import dataclasses
 import os
 import sys
 
@@ -37,20 +43,43 @@ def _import():
         sys.path.insert(0, SRC)
 
 
+# Fields of the named architecture that a configuration file never replaces.
+KEPT = ("name", "family", "source")
+
+
+def _tuples(v):
+    return tuple(_tuples(x) for x in v) if isinstance(v, list) else v
+
+
+def arch_config(config):
+    """The program's `ArchConfig` for a configuration file: the architecture
+    that `config["arch"]` names, with every `ArchConfig` field the file
+    names (but `KEPT`) in its place, lists as tuples.  A file whose `family`
+    is not the architecture's is refused."""
+    _import()
+    from repro.configs import get as get_arch
+
+    base = get_arch(config["arch"])
+    if config["family"] != base.family:
+        raise SystemExit(f"bench: configuration {config['name']!r} is of "
+                         f"family {config['family']!r}, its arch "
+                         f"{config['arch']!r} of {base.family!r}")
+    fields = {f.name for f in dataclasses.fields(base)} - set(KEPT)
+    return base.replace(**{k: _tuples(v) for k, v in config.items()
+                           if k in fields})
+
+
 class Program:
     """Model, optimizer state and compiled step of one cell."""
 
     def __init__(self, config, cell):
         _import()
-        from repro.configs import get as get_arch
         from repro.core.qconfig import preset
         from repro.kernels import ops
         from repro.models import build_model
 
         self.ops = ops
-        self.acfg = get_arch(config["arch"]).replace(
-            block=config["block"], stage_sizes=tuple(config["stage_sizes"]),
-            num_classes=config["num_classes"], img_size=config["img_size"])
+        self.acfg = arch_config(config)
         self.qcfg = preset(config["preset"], config["mode"])
         self.model = build_model(self.acfg, self.qcfg)
         self.dp, self.n_shards = cell["dp"], cell["n_shards"]

@@ -3,14 +3,16 @@
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> \
         --trace <0|1>
 
-Set-up (timed as `setup_s`): turn on the compile cache at a fixed path,
-make the weights and a ring of distinct batches on the device from the
-seed, compile the cell's one step shape, and drive the step through its
-first three steps, whose readings the correctness check keeps.
+Set-up (timed as `setup_s`): refuse a cell whose family lacks its
+reference or work file (`check_family`), turn on the compile cache at a
+fixed path, make the weights and a ring of distinct batches on the device
+from the seed, compile the cell's one step shape, and drive the step
+through its first three steps, whose readings the correctness check keeps.
 
 --trace 0: a closed loop of back-to-back steps for `--seconds`, the host
 enqueuing steps ahead of the device (`AHEAD_*`); the window ends when the
-last step's outputs are ready.  Prints `samples_per_s`, `mfu` and `setup_s`.
+last step's outputs are ready.  Prints `samples_per_s`, `mfu` and `setup_s`;
+a sample is one image, or one sequence of the traffic's `seq` tokens.
 --trace 1: a shorter traced window; prints the per-layer metrics that the
 readers under `metrics/` find in the device trace, with `busy_s`,
 `window_s` and a breakdown.
@@ -103,6 +105,30 @@ class CompileCounter:
         if self.armed and ("/jax/core/compile" in event
                            or "/jax/compilation_cache" in event):
             self.n += 1
+
+
+# What each family has to bring as files of its own: module -> functions.
+FAMILY_FILES = {"reference": ("init_params", "train_step"),
+                "work": ("model_flops_per_sample",)}
+
+
+def check_family(config):
+    """Refuse, before anything is built, a configuration whose family lacks
+    `reference/<family>.py` or `work/<family>.py`, or a function of theirs
+    that every run calls."""
+    family = config["family"]
+    for package, names in FAMILY_FILES.items():
+        module = f"bench.{package}.{family}"
+        path = f"bench/{package}/{family}.py"
+        try:
+            mod = importlib.import_module(module)
+        except ModuleNotFoundError as e:
+            if e.name != module:
+                raise
+            raise SystemExit(f"bench: family {family!r} has no {path}")
+        missing = [n for n in names if not hasattr(mod, n)]
+        if missing:
+            raise SystemExit(f"bench: {path} lacks {', '.join(missing)}")
 
 
 def devices_for(cell, require_tpu: bool):
@@ -212,6 +238,11 @@ def run(args, *, require_tpu: bool = True, fault=None, root: str = BENCH):
     """One run of a cell; returns the result dict.  `fault` wraps the
     compiled step (tests plant faults with it)."""
     cell, config, traffic = spec.resolve(args.workload, root)
+    check_family(config)
+    if traffic["ring"] < C.STEPS:
+        raise SystemExit(f"bench: traffic {cell['traffic']!r} keeps "
+                         f"{traffic['ring']} batches; the correctness check "
+                         f"steps through {C.STEPS} distinct batches")
     program.check_present()
     devs = devices_for(cell, require_tpu)
 
@@ -263,7 +294,7 @@ def run(args, *, require_tpu: bool = True, fault=None, root: str = BENCH):
     else:
         steps, secs = loop.run(seconds=args.seconds)
         sps = steps * traffic["batch"] / secs
-        flops = for_config(config).model_flops_per_sample(config)
+        flops = for_config(config).model_flops_per_sample(config, traffic)
         metrics["samples_per_s"] = {"value": sps, "unit": "samples/s"}
         if peak is not None:
             metrics["mfu"] = {"value": 100.0 * sps * flops

@@ -3,6 +3,8 @@ are found by name; no existing file is touched."""
 import json
 import os
 import shutil
+import sys
+import types
 
 import pytest
 
@@ -44,6 +46,43 @@ def test_new_files_are_found(tmp_path):
     assert got["idle_share"]["value"] == 50.0
     # readers that find nothing to read return nothing
     assert "conv_ms_per_step" not in got and "oracle_calls" not in got
+    for p, data in before.items():
+        assert p.read_bytes() == data
+
+
+def test_new_family_with_token_traffic_is_found(tmp_path, monkeypatch):
+    """A family the benchmark has never run enters by files alone: its
+    configuration, token traffic and cell resolve, and a reader whose count
+    its work file lacks reads nothing."""
+    for d in DIRS:
+        shutil.copytree(os.path.join(BENCH, d), tmp_path / d)
+    before = {p: p.read_bytes() for p in tmp_path.rglob("*") if p.is_file()}
+
+    (tmp_path / "configs" / "decoder.json").write_text(json.dumps({
+        "name": "decoder", "family": "lm", "arch": "granite-3-8b",
+        "n_layers": 2, "d_model": 64, "vocab": 128}))
+    (tmp_path / "traffic" / "t32.json").write_text(json.dumps(
+        {"kind": "tokens", "batch": 2, "seq": 32, "ring": 2}))
+    (tmp_path / "cells" / "decoder.t32.json").write_text(json.dumps(
+        {"config": "decoder", "traffic": "t32", "chips": 1, "dp": 1,
+         "n_shards": 1, "why": "new family", "limits": {}}))
+
+    cell, config, traffic = spec.resolve("decoder.t32", str(tmp_path))
+    assert config["family"] == "lm" and traffic["seq"] == 32
+    work = types.ModuleType("bench.work.lm")
+    work.model_flops_per_sample = lambda cfg, tr: 1e9 * tr["seq"]
+    monkeypatch.setitem(sys.modules, "bench.work.lm", work)
+    ops = [("convolution.1", "conv", 0.0, 2e8),
+           ("quantize_fused.3", "kernel:quantize_fused", 2e8, 1e8)]
+    ctx = {"devices": [ops], "steps": 2, "window_s": 1.0, "busy_s": 0.3,
+           "peaks": {"int8_ops": 1e12, "hbm_bytes_per_s": 1e11},
+           "oracle_calls": 0, "config": config, "traffic": traffic,
+           "chips": 1}
+    got = run.read_metrics(ctx, str(tmp_path))
+    assert got["step_mfu"]["value"] == pytest.approx(100 * 32e9 * 2 * 2
+                                                     / 1e12)
+    assert got["conv_ms_per_step"]["value"] == pytest.approx(100.0)
+    assert "conv_roofline" not in got and "quantize_roofline" not in got
     for p, data in before.items():
         assert p.read_bytes() == data
 

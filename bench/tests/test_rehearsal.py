@@ -7,13 +7,15 @@ import os
 import shutil
 import subprocess
 import sys
+import types
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from bench import correct as C
-from bench import run, spec
+from bench import program, run, spec
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 DATA = os.path.join(HERE, "data")
@@ -105,6 +107,98 @@ def test_rehearsal_dp4_on_four_virtual_devices():
     res = json.loads(out.stdout.strip().splitlines()[-1])
     assert res["device"]["count"] == 4
     assert res["correct"], res["checks"]
+
+
+def _lm_model(config):
+    from repro.core.qconfig import preset
+    from repro.models import build_model
+    return build_model(program.arch_config(config),
+                       preset(config["preset"], config["mode"]))
+
+
+def _lm_reference():
+    """A stand-in for `reference/lm.py`, for the harness only: its weights
+    are the program's own `model.init`, its step plain SGD on the program's
+    loss.  It is no reference, so the rehearsal judges no `correct`."""
+    ref = types.ModuleType("bench.reference.lm")
+    ref.init_params = lambda config, key: _lm_model(config).init(key)
+
+    def train_step(config, p, acc, batch, key, n_shards=1):
+        (loss, _), g = jax.value_and_grad(_lm_model(config).loss,
+                                          has_aux=True)(p, batch, key)
+        lr = config["optimizer"]["lr"]
+        return jax.tree.map(lambda w, d: w - lr * d, p, g), acc, loss, g
+
+    ref.train_step = train_step
+    return ref
+
+
+def _lm_work():
+    """A stand-in for `work/lm.py`: 6 FLOPs a weight of the layers' matmuls
+    and the head, and a token.  Plain arithmetic: a work count traces
+    nothing, since the window is over when `run.py` asks for it."""
+    work = types.ModuleType("bench.work.lm")
+
+    def model_flops_per_sample(config, traffic):
+        d, dh, f = config["d_model"], config["head_dim"], config["d_ff"]
+        attn = 2 * d * dh * (config["n_heads"] + config["n_kv"])
+        layer = attn + 3 * d * f
+        return 6 * (config["n_layers"] * layer + d * config["vocab"]) \
+            * traffic["seq"]
+
+    work.model_flops_per_sample = model_flops_per_sample
+    return work
+
+
+def test_rehearsal_token_traffic_cell(monkeypatch):
+    """A dense decoder on token traffic runs through the whole harness with
+    nothing in it but files of its own (and these test stand-ins)."""
+    monkeypatch.setitem(sys.modules, "bench.reference.lm", _lm_reference())
+    monkeypatch.setitem(sys.modules, "bench.work.lm", _lm_work())
+    builds, rings = [], []
+    build, ring = program.Program.build, run.traffic_ring
+
+    def counted_build(self, params, batch):
+        builds.append(batch)
+        return build(self, params, batch)
+
+    def kept_ring(*a, **kw):
+        rings.append(ring(*a, **kw))
+        return rings[-1]
+
+    monkeypatch.setattr(program.Program, "build", counted_build)
+    monkeypatch.setattr(run, "traffic_ring", kept_ring)
+    res = _run("tinylm.t32")
+    assert len(builds) == 1
+    assert res["checks"]["compiles_in_window"]["value"] == 0
+    assert res["checks"]["nonfinite_losses"]["value"] == 0
+    assert res["attempted"] > 0 and res["failed"] == 0
+    assert {"samples_per_s", "setup_s"} <= set(res["metrics"])
+    (batches,) = rings
+    assert len(batches) == 3
+    for b in batches:
+        for x in (b["tokens"], b["labels"]):
+            assert x.shape == (2, 32) and x.dtype == jnp.int32
+        np.testing.assert_array_equal(np.asarray(b["tokens"])[:, 1:],
+                                      np.asarray(b["labels"])[:, :-1])
+
+
+def test_setup_names_a_missing_work_file(monkeypatch):
+    monkeypatch.setitem(sys.modules, "bench.reference.lm", _lm_reference())
+    monkeypatch.delitem(sys.modules, "bench.work.lm", raising=False)
+    with pytest.raises(SystemExit, match=r"no bench/work/lm\.py"):
+        _run("tinylm.t32")
+
+
+def test_ring_shorter_than_the_checked_steps_is_refused(tmp_path):
+    for d in ("cells", "configs", "traffic"):
+        shutil.copytree(os.path.join(DATA, d), tmp_path / d)
+    (tmp_path / "traffic" / "tiny-b8.json").write_text(
+        json.dumps({"batch": 8, "ring": C.STEPS - 1}))
+    args = run.parse(["--workload", "tiny18.b8", "--seed", "1",
+                      "--seconds", "1", "--trace", "0"])
+    with pytest.raises(SystemExit, match="distinct batches"):
+        run.run(args, require_tpu=False, root=str(tmp_path))
 
 
 def _cli(cwd):

@@ -7,15 +7,17 @@ compiled program, so a change of implementation leaves the yardstick alone.
 `convs(cfg)` lists every convolution with its input size; the rest derive
 from it:
   * `fwd_macs`: multiply-adds of one image's forward convs and FC;
-  * `model_flops_per_sample`: 3 x 2 x fwd_macs (forward, input gradient,
-    weight gradient), the usual training convention;
-  * `conv_work(cfg, batch)`: FLOPs and least bytes of the convolutions a
-    step has to run (forward of all; weight gradient of all; input gradient
-    of all but the stem, whose input is the image), with int8 operands and
-    float32 results;
-  * `quantized_elements(cfg, batch)`: elements a step puts on an int8 grid
-    through Q_W (every conv weight but the stem's) and Q_A (every
+  * `model_flops_per_sample(cfg, traffic)`: 3 x 2 x fwd_macs (forward,
+    input gradient, weight gradient), the usual training convention;
+  * `conv_work(cfg, traffic, batch)`: FLOPs and least bytes of the
+    convolutions a step has to run (forward of all; weight gradient of all;
+    input gradient of all but the stem, whose input is the image), with
+    int8 operands and float32 results;
+  * `quantized_elements(cfg, traffic, batch)`: elements a step puts on an
+    int8 grid through Q_W (every conv weight but the stem's) and Q_A (every
     activation quantizer: after the stem's max-pool and after each ReLU).
+A sample is one image at the configuration's size, so `traffic` (the
+signatures of `work/__init__.py`) changes no count here.
 """
 from __future__ import annotations
 
@@ -79,11 +81,11 @@ def param_count(cfg):
     return sum(_conv_shape(c)[2] for c in convs(cfg)) + bn + fc
 
 
-def model_flops_per_sample(cfg):
+def model_flops_per_sample(cfg, traffic):
     return 6 * fwd_macs(cfg)
 
 
-def conv_work(cfg, batch):
+def conv_work(cfg, traffic, batch):
     """(flops, least bytes) of one step's convolutions: int8 operands,
     float32 results, each pass reading its operands and writing its result
     once."""
@@ -100,8 +102,13 @@ def conv_work(cfg, batch):
     return flops, nbytes
 
 
-def quantized_elements(cfg, batch):
-    """Elements one step quantizes to int8 through Q_W and Q_A."""
+def quantized_elements(cfg, traffic, batch=None):
+    """Elements one step quantizes to int8 through Q_W and Q_A.
+
+    `quantized_elements(cfg, batch)`, with no traffic, is the form that
+    `tests/test_kernels.py` calls: an image's count takes none."""
+    if batch is None:
+        batch = traffic
     weights = sum(_conv_shape(c)[2] for c in convs(cfg) if c[0] != "stem")
     h = _out(_out(cfg["img_size"], 2), 2)
     acts = h * h * 64                                  # after the max-pool
